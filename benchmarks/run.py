@@ -25,6 +25,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smaller corpora (CI)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import common
     from . import (bulk, compaction, compression, construction, fpr,

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import codec as _codec
+from ..kernels.ops import PackedArena, pack_lines
 
 
 @dataclasses.dataclass(frozen=True)
@@ -581,17 +582,20 @@ class DeviceTileCache:
         import jax
         return jax.device_put(host, self.device)
 
-    def _stage(self, s: int) -> jnp.ndarray:
-        if not self.pad_rows_to:
-            return (self.storage.shard_device(s) if self.device is None
-                    else self._put(self.storage.shard_host(s)))
+    def _put_packed(self, host: np.ndarray) -> PackedArena:
+        """Stage a [rows, W] host array as the lane-packed device form
+        the gather kernels read (see ``repro.kernels.ops.PackedArena``)."""
+        host = np.asarray(host)
+        return PackedArena(self._put(pack_lines(host)), *host.shape)
+
+    def _stage(self, s: int) -> PackedArena:
         host = self.storage.shard_host(s)
-        pad = self.pad_rows_to - host.shape[0]
+        pad = (self.pad_rows_to or host.shape[0]) - host.shape[0]
         if pad < 0:
             raise ValueError(f"shard {s} taller than pad_rows_to")
-        if pad == 0 and self.device is None:
-            return self.storage.shard_device(s)
-        return self._put(np.pad(host, ((0, pad), (0, 0))))
+        if pad:
+            host = np.pad(host, ((0, pad), (0, 0)))
+        return self._put_packed(host)
 
     def _stage_compressed(self, s: int) -> tuple:
         d = self.storage.shard_dict_host(s)
@@ -610,7 +614,7 @@ class DeviceTileCache:
             raise ValueError(f"shard {s} taller than pad_rows_to")
         if r_pad:                  # padded rows ref slot 0; never addressed
             refs = np.pad(np.asarray(refs), (0, r_pad))
-        return (self._put(np.ascontiguousarray(dict_rows)),
+        return (self._put_packed(dict_rows),
                 self._put(np.ascontiguousarray(refs)))
 
     def _tile_nbytes(self, s: int) -> int:
@@ -691,11 +695,12 @@ class DeviceTileCache:
             self._notify(s, "fault", staged_s)
             return tile
 
-    def get(self, s: int) -> jnp.ndarray:
+    def get(self, s: int) -> PackedArena:
+        """Shard ``s`` on device as a lane-packed [rows, W] arena."""
         return self._get(s)
 
     def get_compressed(self, s: int) -> tuple:
-        """(dict_tile uint32 [D_pad, W], refs int32 [pad_rows_to]) on
+        """(dict_tile PackedArena [D_pad, W], refs int32 [pad_rows_to]) on
         device — the fused kernels' decode inputs. D is padded to a pow2
         (floor 8) so kernel shapes bucket; refs pad with slot 0."""
         return self._get(("c", s))

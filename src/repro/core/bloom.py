@@ -20,7 +20,7 @@ import numpy as np
 from . import hashing
 
 ROW_ALIGN = 512      # filter widths rounded up -> fewer jit traces, aligned rows
-TERM_ALIGN = 1024    # term-count padding granularity for the build scatter
+TERM_ALIGN = 1024    # least term-count padding for the build scatter
 DOC_WORD_BITS = 32   # documents per packed word
 
 
@@ -87,7 +87,10 @@ def build_block_matrix(
         counts = np.array([d.shape[0] for d in docs] + [0] * (c1 - c0 - len(docs)),
                           dtype=np.int32)
         t_max = int(counts.max()) if counts.size else 0
-        t_pad = max(TERM_ALIGN, ((t_max + TERM_ALIGN - 1) // TERM_ALIGN) * TERM_ALIGN)
+        # pow2 padding: a skewed corpus then compiles a few scatter shapes
+        # per block width instead of one per chunk (each TPU compile of
+        # a multi-million-term scatter takes seconds)
+        t_pad = max(TERM_ALIGN, 1 << max(0, t_max - 1).bit_length())
         buf = np.zeros((c1 - c0, t_pad, 2), dtype=np.uint32)
         for i, d in enumerate(docs):
             buf[i, : d.shape[0]] = d

@@ -37,20 +37,14 @@ def decode_dna(codes: np.ndarray) -> str:
 
 
 def _pack_windows(win: np.ndarray) -> np.ndarray:
-    """Pack 2-bit code windows [n, k] into uint32 pairs [n, 2] (lo, hi)."""
+    """Pack 2-bit code windows [n, k] into uint32 pairs [n, 2] (lo, hi).
+    Column by column, so no [n, k] temporary is ever built."""
     n, k = win.shape
-    lo_n = min(k, 16)
     out = np.zeros((n, 2), dtype=np.uint32)
-    if n == 0:
-        return out
-    sh_lo = (2 * np.arange(lo_n, dtype=np.uint32))[None, :]
-    out[:, 0] = np.bitwise_or.reduce(win[:, :lo_n].astype(np.uint32) << sh_lo, axis=1)
-    if k > 16:
-        hi_n = k - 16
-        sh_hi = (2 * np.arange(hi_n, dtype=np.uint32))[None, :]
-        out[:, 1] = np.bitwise_or.reduce(
-            win[:, 16:].astype(np.uint32) << sh_hi, axis=1
-        )
+    for half, (c0, c1) in enumerate(((0, min(k, 16)), (16, k))):
+        col = out[:, half]
+        for i in range(c0, c1):
+            col |= win[:, i].astype(np.uint32) << np.uint32(2 * (i - c0))
     return out
 
 

@@ -936,10 +936,10 @@ def run_shard_major(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
 
 def gather_rows(arena: jnp.ndarray, rows: jnp.ndarray, valid: jnp.ndarray
                 ) -> jnp.ndarray:
-    """Gather + AND + mask: (arena [R, Wb], rows int32 [L, k, nb],
-    valid bool [L]) -> uint32 [L, nb * Wb]."""
+    """Gather + AND + mask: (arena [R, Wb] or PackedArena, rows int32
+    [L, k, nb], valid bool [L]) -> uint32 [L, nb * Wb]."""
     L, k, nb = rows.shape
-    g = arena[rows]                               # [L, k, nb, Wb]
+    g = ops.take_rows(arena, rows)                # [L, k, nb, Wb]
     anded = g[:, 0]
     for i in range(1, k):
         anded = anded & g[:, i]
@@ -953,7 +953,7 @@ def gather_rows_comp(dict_rows: jnp.ndarray, refs: jnp.ndarray,
     ``dict_rows[refs[rows]]`` decodes on the fly — same AND + mask, same
     output, HBM traffic proportional to the dict instead of the tile."""
     L, k, nb = rows.shape
-    g = dict_rows[refs[rows]]                     # [L, k, nb, Wb]
+    g = ops.take_rows(dict_rows, refs[rows])      # [L, k, nb, Wb]
     anded = g[:, 0]
     for i in range(1, k):
         anded = anded & g[:, i]

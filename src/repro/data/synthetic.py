@@ -12,6 +12,8 @@ no k-mer with any document.
 """
 from __future__ import annotations
 
+import multiprocessing
+import os
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -51,6 +53,17 @@ class SyntheticCorpus:
         return np.array([t.shape[0] for t in self.doc_terms], dtype=np.int64)
 
 
+def lognormal_lengths(rng: np.random.Generator, n_docs: int, *,
+                      mean_length: int, sigma: float = 1.0,
+                      min_length: int = 64, max_length: int | None = None
+                      ) -> np.ndarray:
+    """Document lengths in bases: log-normal around ``mean_length``,
+    clipped to [min_length, max_length or 50 * mean_length]."""
+    mu = np.log(mean_length) - sigma ** 2 / 2
+    lengths = np.exp(rng.normal(mu, sigma, size=n_docs)).astype(np.int64)
+    return np.clip(lengths, min_length, max_length or 50 * mean_length)
+
+
 def make_corpus(
     n_docs: int,
     *,
@@ -68,9 +81,9 @@ def make_corpus(
     at a few thousand documents.
     """
     rng = np.random.default_rng(seed)
-    mu = np.log(mean_length) - sigma ** 2 / 2
-    lengths = np.exp(rng.normal(mu, sigma, size=n_docs)).astype(np.int64)
-    lengths = np.clip(lengths, min_length, max_length or 50 * mean_length)
+    lengths = lognormal_lengths(rng, n_docs, mean_length=mean_length,
+                                sigma=sigma, min_length=min_length,
+                                max_length=max_length)
     docs, terms = [], []
     for i in range(n_docs):
         g = random_genome(rng, int(lengths[i]))
@@ -78,6 +91,44 @@ def make_corpus(
         terms.append(dna.document_terms([g], k, canonical))
     return SyntheticCorpus(docs, terms, k, canonical,
                            [f"doc{i:06d}" for i in range(n_docs)])
+
+
+def doc_genome(seed: int, doc: int, length: int) -> np.ndarray:
+    """Document ``doc``'s genome, reproducible from (seed, doc) alone — so
+    documents generate in parallel and a query can be cut from one
+    without keeping the corpus."""
+    return random_genome(np.random.default_rng([seed, doc]), int(length))
+
+
+def _docs_terms(args) -> list[np.ndarray]:
+    seed, first, lengths, k = args
+    return [dna.document_terms([doc_genome(seed, first + j, n)], k)
+            for j, n in enumerate(lengths)]
+
+
+def _numpy_only() -> None:
+    # generation children never need a device; keep them off the one the
+    # parent process holds
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
+
+def make_corpus_terms(lengths: np.ndarray, *, k: int = 31, seed: int = 0,
+                      workers: int = 1) -> list[np.ndarray]:
+    """Distinct k-mers of every ``doc_genome(seed, i, lengths[i])``.
+
+    With ``workers`` > 1 the documents are generated by that many fresh
+    (spawned) NumPy-only processes — the bulk of the work holds the GIL,
+    so threads would not scale. The result depends on ``seed`` only."""
+    lengths = np.asarray(lengths, dtype=np.int64)
+    step = max(1, len(lengths) // (8 * max(1, int(workers))))
+    jobs = [(seed, i, lengths[i:i + step], k)
+            for i in range(0, len(lengths), step)]
+    if workers <= 1:
+        parts = map(_docs_terms, jobs)
+        return [t for part in parts for t in part]
+    ctx = multiprocessing.get_context("spawn")
+    with ctx.Pool(int(workers), initializer=_numpy_only) as pool:
+        return [t for part in pool.imap(_docs_terms, jobs) for t in part]
 
 
 def make_queries(

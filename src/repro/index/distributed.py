@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 
 from ..core import dna, hashing
 from ..core.index import BitSlicedIndex

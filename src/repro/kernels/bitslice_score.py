@@ -148,194 +148,186 @@ def vertical_score(
 
 
 # --------------------------------------------------------------------------
-# 3. fused lookup+score kernel (scalar-prefetched row gather from the arena)
+# 3. row-gather kernels over a lane-packed arena
 # --------------------------------------------------------------------------
+#
+# On TPU a [R, W] uint32 arena with W < 128 words is laid out with R on the
+# lanes (no padding), and Mosaic can neither DMA a W-word slice of it nor
+# read it as a row-major operand without XLA copying the whole arena first.
+# The gather kernels therefore read the arena as 128-lane LINES: a row of
+# ``ws`` stored words (W rounded up to a power of two below 128, or to a
+# multiple of 128 above) occupies either one segment of a line
+# (``per_line`` = 128 // ws rows per line) or ``lines`` = ws // 128 whole
+# lines. ``repro.kernels.ops.pack_lines`` builds that [N, 128] view; for
+# the paper's 1024-document blocks (W = 32) it is a free reshape of the
+# host arena and holds the same bytes as the logical array.
+#
+# A term's row is fetched as the (8, 128) tile that contains its line (the
+# smallest block the TPU pipeline accepts), the line is picked from the
+# tile by sublane, and lanes outside the row's segment are masked off. The
+# Harley-Seal planes then accumulate 128 lanes; at the end the per_line
+# segments are folded (summed), which is exact because a row only ever
+# contributes to its own segment. Outputs are int32 [..., ws, 32].
+#
+# Row indices and masks ride scalar prefetch (SMEM). The ops wrappers
+# split a dispatch so that no [Q, nb, L] table outgrows scalar memory.
 
-def _lookup_kernel(idx_ref, mask_ref, arena_ref, out_ref, planes_ref, *,
-                   n_planes: int):
-    i_l = pl.program_id(1)
-    n_l = pl.num_programs(1)
-
-    @pl.when(i_l == 0)
-    def _init():
-        planes_ref[...] = jnp.zeros_like(planes_ref)
-
-    row = arena_ref[0, :]                                   # uint32 [bw]
-    row = row * mask_ref[i_l].astype(jnp.uint32)            # mask invalid term
-    carry = row
-    for j in range(n_planes):
-        new_carry = planes_ref[j, :] & carry
-        planes_ref[j, :] = planes_ref[j, :] ^ carry
-        carry = new_carry
-
-    @pl.when(i_l == n_l - 1)
-    def _expand():
-        shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-        acc = jnp.zeros(out_ref.shape, jnp.int32)
-        for j in range(n_planes):
-            bits = ((planes_ref[j, :][:, None] >> shifts) & jnp.uint32(1))
-            acc += bits.astype(jnp.int32) << j
-        out_ref[...] = acc
+LANES = 128
+SUBLANES = 8
 
 
-def _lookup_blocks_kernel(idx_ref, mask_ref, arena_ref, out_ref, planes_ref,
-                          *, n_planes: int):
-    il = pl.program_id(2)
-    n_l = pl.num_programs(2)
-
-    @pl.when(il == 0)
-    def _init():
-        planes_ref[...] = jnp.zeros_like(planes_ref)
-
-    ib = pl.program_id(1)
-    row = arena_ref[0, :] * mask_ref[ib, il].astype(jnp.uint32)
-    carry = row
-    for j in range(n_planes):
-        new_carry = planes_ref[j, :] & carry
-        planes_ref[j, :] = planes_ref[j, :] ^ carry
-        carry = new_carry
-
-    @pl.when(il == n_l - 1)
-    def _expand():
-        shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-        acc = jnp.zeros(out_ref.shape[1:], jnp.int32)
-        for j in range(n_planes):
-            bits = ((planes_ref[j, :][:, None] >> shifts) & jnp.uint32(1))
-            acc += bits.astype(jnp.int32) << j
-        out_ref[0] = acc
+def row_geometry(words: int) -> tuple[int, int, int]:
+    """Logical row width in words -> (ws, per_line, lines): the stored
+    row width, rows per 128-lane line and lines per row."""
+    w = max(1, int(words))
+    if w < LANES:
+        ws = 1 << (w - 1).bit_length()
+        return ws, LANES // ws, 1
+    ws = -(-w // LANES) * LANES
+    return ws, 1, ws // LANES
 
 
-def lookup_score_blocks(
-    arena: jnp.ndarray,
-    rows_idx: jnp.ndarray,
-    mask: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Multi-block fused gather+score (the compact-index hot loop).
-
-    arena uint32 [R, W]; rows_idx int32 [nb, L] (term row per sub-index
-    block); mask int32 [nb, L] -> int32 [nb, W, 32]. Each (word-tile, block)
-    cell streams its L rows HBM->VMEM via scalar-prefetched indices and
-    accumulates vertical (Harley-Seal) counters — the [L, nb, W] gathered
-    intermediate of the unfused path never exists.
-    """
-    R, W = arena.shape
-    nb, L = rows_idx.shape
-    n_planes = _num_planes(L)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(W // word_block, nb, L),
-        in_specs=[
-            pl.BlockSpec((1, word_block),
-                         lambda iw, ib, il, idx, msk: (idx[ib, il], iw)),
-        ],
-        out_specs=pl.BlockSpec((1, word_block, 32),
-                               lambda iw, ib, il, idx, msk: (ib, iw, 0)),
-        scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
-    )
-    kernel = functools.partial(_lookup_blocks_kernel, n_planes=n_planes)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, W, 32), jnp.int32),
-        interpret=interpret,
-    )(rows_idx, mask, arena)
+def _line_of(r, iw, per_line: int, lines: int):
+    """Line index of word tile ``iw`` of row ``r`` (traced int32)."""
+    if per_line > 1:
+        return r // per_line
+    return r * lines + iw
 
 
-def _lookup_multi_kernel(idx_ref, mask_ref, arena_ref, out_ref, planes_ref,
-                         *, n_planes: int, q_axis: int = 1, b_axis: int = 2):
+def _fetch(arena_ref, r, iw, *, per_line: int, lines: int, ws: int):
+    """Row ``r``'s 128-lane word tile ``iw`` from its resident (8, 128)
+    arena tile, with lanes of other rows in the same line zeroed."""
+    sub = _line_of(r, iw, per_line, lines) % SUBLANES
+    row = arena_ref[pl.ds(sub, 1), :]                       # uint32 [1, 128]
+    if per_line > 1:
+        seg = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // ws
+        row = jnp.where(seg == r % per_line, row, jnp.uint32(0))
+    return row
+
+
+def _ripple(planes, carry):
+    """Add one row into Harley-Seal counter planes (list of [1, 128])."""
+    out = []
+    for p in planes:
+        out.append(p ^ carry)
+        carry = p & carry
+    return out
+
+
+def _expand(planes, ws: int) -> jnp.ndarray:
+    """Counter planes -> int32 [min(ws, 128), 32] per-word bit counts,
+    with the per_line segments of a line folded together."""
+    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
+    acc = jnp.zeros((LANES, 32), jnp.int32)
+    for j, p in enumerate(planes):
+        bits = (p[0][:, None] >> shifts) & jnp.uint32(1)
+        acc += bits.astype(jnp.int32) << j
+    if ws < LANES:
+        acc = sum(acc[s * ws:(s + 1) * ws] for s in range(LANES // ws))
+    return acc
+
+
+def _lookup_kernel(idx_ref, mask_ref, arena_ref, *rest, n_planes: int,
+                   per_line: int, lines: int, ws: int, axes: tuple,
+                   has_acc: bool):
+    acc_ref = rest[0] if has_acc else None
+    out_ref, planes_ref = rest[-2], rest[-1]
+    iw, iq, ib = (pl.program_id(a) for a in axes)
     il = pl.program_id(3)
-    n_l = pl.num_programs(3)
 
     @pl.when(il == 0)
     def _init():
         planes_ref[...] = jnp.zeros_like(planes_ref)
 
-    iq = pl.program_id(q_axis)
-    ib = pl.program_id(b_axis)
-    row = arena_ref[0, :] * mask_ref[iq, ib, il].astype(jnp.uint32)
-    carry = row
+    r = idx_ref[iq, ib, il]
+    row = _fetch(arena_ref, r, iw, per_line=per_line, lines=lines, ws=ws)
+    row = row * mask_ref[iq, ib, il].astype(jnp.uint32)
+    planes = _ripple([planes_ref[pl.ds(j, 1), :] for j in range(n_planes)],
+                     row)
     for j in range(n_planes):
-        new_carry = planes_ref[j, :] & carry
-        planes_ref[j, :] = planes_ref[j, :] ^ carry
-        carry = new_carry
+        planes_ref[pl.ds(j, 1), :] = planes[j]
 
-    @pl.when(il == n_l - 1)
-    def _expand():
-        shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-        acc = jnp.zeros(out_ref.shape[2:], jnp.int32)
-        for j in range(n_planes):
-            bits = ((planes_ref[j, :][:, None] >> shifts) & jnp.uint32(1))
-            acc += bits.astype(jnp.int32) << j
-        out_ref[0, 0] = acc
+    @pl.when(il == pl.num_programs(3) - 1)
+    def _finish():
+        counts = _expand([planes_ref[pl.ds(j, 1), :]
+                          for j in range(n_planes)], ws)
+        if has_acc:
+            counts = counts + acc_ref[0, 0]
+        out_ref[0, 0] = counts
 
 
 GRID_ORDERS = ("wq", "qw")
 
 
 def lookup_score_multi(
-    arena: jnp.ndarray,
+    arena_lines: jnp.ndarray,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
+    acc: jnp.ndarray | None = None,
     *,
-    word_block: int = DEFAULT_WORD_BLOCK,
+    per_line: int,
+    ws: int,
     grid_order: str = "wq",
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused MULTI-QUERY gather+score (the batched-serving hot loop).
+    """Fused multi-query gather+score (the batched-serving hot loop).
 
-    arena uint32 [R, W]; rows_idx int32 [Q, nb, L] (term row per query per
-    sub-index block); mask int32 [Q, nb, L] -> int32 [Q, nb, W, 32].
+    arena_lines uint32 [N, 128] (see ``row_geometry``); rows_idx int32
+    [Q, nb, L] (term row per query per sub-index block); mask int32
+    [Q, nb, L]; optional acc int32 [Q, nb, ws, 32] of running counts
+    (the pruned executor's term chunks) -> int32 [Q, nb, ws, 32].
 
-    The batched generalization of lookup_score_blocks: the grid grows a
-    query dimension, every (word-tile, query, block) cell streams its L
-    rows HBM->VMEM via scalar-prefetched indices and keeps Harley-Seal
-    counter planes in a single shared VMEM scratch. Queries share arena
-    tiles through the same BlockSpec pipeline, so a batch never
-    materializes the [Q, L, W] gather that forces the unfused path to the
-    pure-jnp ref scorer under vmap.
-
-    ``grid_order`` permutes the outer grid axes (autotuner knob):
-    'wq' = (word, query, block, term) — word tiles outermost, so one
-    query's whole accumulation streams before the next word tile; 'qw' =
-    (query, block, word, term) — queries outermost, so a query's term rows
-    stream word-tile by word-tile. Term stays innermost either way (the
-    counter-plane scratch accumulates over it); both orders are
-    bit-identical and differ only in DMA locality.
+    Every (word tile, query, block) cell streams its L rows HBM->VMEM via
+    scalar-prefetched indices into Harley-Seal planes in one VMEM scratch,
+    so the [Q, nb, L, W] gather never exists. ``grid_order`` 'wq' puts the
+    word tiles outermost, 'qw' the queries; both are bit-identical.
     """
-    R, W = arena.shape
     Q, nb, L = rows_idx.shape
-    n_planes = _num_planes(L)
+    lines = max(1, ws // LANES)
+    tw = min(ws, LANES)
     if grid_order == "wq":
-        grid = (W // word_block, Q, nb, L)
-        arena_map = lambda iw, iq, ib, il, idx, msk: (idx[iq, ib, il], iw)
-        out_map = lambda iw, iq, ib, il, idx, msk: (iq, ib, iw, 0)
-        q_axis, b_axis = 1, 2
+        grid, axes = (lines, Q, nb, L), (0, 1, 2)
     elif grid_order == "qw":
-        grid = (Q, nb, W // word_block, L)
-        arena_map = lambda iq, ib, iw, il, idx, msk: (idx[iq, ib, il], iw)
-        out_map = lambda iq, ib, iw, il, idx, msk: (iq, ib, iw, 0)
-        q_axis, b_axis = 0, 1
+        grid, axes = (Q, nb, lines, L), (2, 0, 1)
     else:
         raise ValueError(f"unknown grid_order {grid_order!r}; "
                          f"one of {GRID_ORDERS}")
+
+    def cell(*g):
+        return tuple(g[a] for a in axes) + (g[3],)
+
+    def arena_map(*g):
+        (iw, iq, ib, il), idx = cell(*g[:4]), g[4]
+        r = idx[iq, ib, il]
+        return _line_of(r, iw, per_line, lines) // SUBLANES, 0
+
+    def out_map(*g):
+        iw, iq, ib, _ = cell(*g[:4])
+        return iq, ib, iw, 0
+
+    out_spec = pl.BlockSpec((1, 1, tw, 32), out_map)
+    in_specs = [pl.BlockSpec((SUBLANES, LANES), arena_map)]
+    operands = [rows_idx, mask, arena_lines]
+    if acc is not None:
+        in_specs.append(out_spec)
+        operands.append(acc)
+    n_planes = _num_planes(L)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, word_block), arena_map)],
-        out_specs=pl.BlockSpec((1, 1, word_block, 32), out_map),
-        scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
+        in_specs=in_specs,
+        out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((n_planes, LANES), jnp.uint32)],
     )
-    kernel = functools.partial(_lookup_multi_kernel, n_planes=n_planes,
-                               q_axis=q_axis, b_axis=b_axis)
+    kernel = functools.partial(
+        _lookup_kernel, n_planes=n_planes, per_line=per_line, lines=lines,
+        ws=ws, axes=axes, has_acc=acc is not None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, nb, W, 32), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((Q, nb, ws, 32), jnp.int32),
         interpret=interpret,
-    )(rows_idx, mask, arena)
+    )(*operands)
 
 
 # --------------------------------------------------------------------------
@@ -343,527 +335,129 @@ def lookup_score_multi(
 # --------------------------------------------------------------------------
 #
 # Real serving batches share rows heavily (overlapping k-mers between
-# queries), but the fused multi-query kernel re-DMAs an arena tile from HBM
-# for every (query, block, term) grid cell. The dedup pair makes arena
-# traffic scale with UNIQUE rows instead:
+# queries), but the fused multi-query kernel re-DMAs an arena tile for
+# every (query, block, term) grid cell. The dedup pair makes arena traffic
+# scale with UNIQUE rows instead:
 #
 #   gather_rows   — streams each unique arena row HBM->VMEM exactly ONCE
-#                   and writes the compact [U, W] unique-row matrix.
-#   dedup_score   — scores every (query, block) cell against that compact
-#                   matrix: the [U_pad, word_block] tile is one pipeline
-#                   block whose index map depends only on the word axis, so
-#                   it stays resident in VMEM across all (query, block)
-#                   steps of a word tile; per term the kernel reads the
-#                   indirection index from scalar memory and ripple-carries
-#                   the VMEM row into Harley-Seal counter planes.
+#                   and writes the compact [U, lines * 128] matrix (each row
+#                   in its own line, at its segment's lanes).
+#   dedup_score   — scores every (query, block) cell against that matrix:
+#                   the [U, 128] tile's index map depends only on the word
+#                   axis, so it stays resident in VMEM across all (query,
+#                   block) steps of a word tile; per term the kernel reads
+#                   the indirection index from scalar memory and ripple-
+#                   carries the VMEM row into Harley-Seal counter planes.
 #
 # Host-side planning (repro.core.query.plan_dedup_batch) builds the unique
 # row list and the [Q, nb, L] indirection.
 
 
-def _gather_kernel(idx_ref, arena_ref, out_ref):
-    out_ref[...] = arena_ref[...]
+def _gather_kernel(idx_ref, arena_ref, out_ref, *, per_line: int,
+                   lines: int, ws: int):
+    iw, iu = pl.program_id(0), pl.program_id(1)
+    row = _fetch(arena_ref, idx_ref[iu], iw, per_line=per_line, lines=lines,
+                 ws=ws)
+    out_ref[pl.ds(iu % SUBLANES, 1), :] = row
 
 
 def gather_rows(
-    arena: jnp.ndarray,
+    arena_lines: jnp.ndarray,
     uniq_idx: jnp.ndarray,
     *,
-    word_block: int = DEFAULT_WORD_BLOCK,
+    per_line: int,
+    ws: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Unique-row gather: (arena uint32 [R, W], uniq_idx int32 [U]) ->
-    uint32 [U, W]. Each arena row tile is DMA'd HBM->VMEM exactly once —
-    U * (W / word_block) row-tile transfers total, however many query
-    cells reference the row downstream."""
-    R, W = arena.shape
+    """Unique-row gather: (arena_lines uint32 [N, 128], uniq_idx int32 [U],
+    U % 8 == 0) -> uint32 [U, lines * 128], row u in line u at its arena
+    segment's lanes (other lanes zero) — the input ``dedup_score`` folds."""
     U = uniq_idx.shape[0]
+    lines = max(1, ws // LANES)
+
+    def arena_map(iw, iu, idx):
+        return _line_of(idx[iu], iw, per_line, lines) // SUBLANES, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(W // word_block, U),
-        in_specs=[
-            pl.BlockSpec((1, word_block), lambda iw, iu, idx: (idx[iu], iw)),
-        ],
-        out_specs=pl.BlockSpec((1, word_block), lambda iw, iu, idx: (iu, iw)),
+        grid=(lines, U),
+        in_specs=[pl.BlockSpec((SUBLANES, LANES), arena_map)],
+        out_specs=pl.BlockSpec((SUBLANES, LANES),
+                               lambda iw, iu, idx: (iu // SUBLANES, iw)),
     )
+    kernel = functools.partial(_gather_kernel, per_line=per_line,
+                               lines=lines, ws=ws)
     return pl.pallas_call(
-        _gather_kernel,
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((U, W), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((U, lines * LANES), jnp.uint32),
         interpret=interpret,
-    )(uniq_idx, arena)
+    )(uniq_idx, arena_lines)
 
 
-def _dedup_score_kernel(indir_ref, mask_ref, uniq_ref, out_ref, *,
-                        n_planes: int, n_terms: int):
-    iq = pl.program_id(1)
-    ib = pl.program_id(2)
-    wb = uniq_ref.shape[1]
+def _dedup_score_kernel(indir_ref, mask_ref, uniq_ref, *rest, n_planes: int,
+                        n_terms: int, ws: int, has_acc: bool):
+    out_ref = rest[-1]
+    iq, ib = pl.program_id(1), pl.program_id(2)
 
     def add_term(il, planes):
-        u = indir_ref[iq, ib, il]
-        row = (uniq_ref[pl.ds(u, 1), :][0]
+        row = (uniq_ref[pl.ds(indir_ref[iq, ib, il], 1), :]
                * mask_ref[iq, ib, il].astype(jnp.uint32))
-        carry = row
-        nxt = []
-        for j in range(n_planes):
-            new_carry = planes[j] & carry
-            nxt.append(planes[j] ^ carry)
-            carry = new_carry
-        return tuple(nxt)
+        return tuple(_ripple(list(planes), row))
 
-    planes = tuple(jnp.zeros((wb,), jnp.uint32) for _ in range(n_planes))
+    planes = tuple(jnp.zeros((1, LANES), jnp.uint32)
+                   for _ in range(n_planes))
     planes = jax.lax.fori_loop(0, n_terms, add_term, planes)
-
-    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-    acc = jnp.zeros((wb, 32), jnp.int32)
-    for j in range(n_planes):
-        bits = ((planes[j][:, None] >> shifts) & jnp.uint32(1))
-        acc += bits.astype(jnp.int32) << j
-    out_ref[0, 0] = acc
+    counts = _expand(planes, ws)
+    if has_acc:
+        counts = counts + rest[0][0, 0]
+    out_ref[0, 0] = counts
 
 
 def dedup_score(
     uniq: jnp.ndarray,
     indir: jnp.ndarray,
     mask: jnp.ndarray,
+    acc: jnp.ndarray | None = None,
     *,
-    word_block: int = DEFAULT_WORD_BLOCK,
+    ws: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Indirected multi-query score over a gathered unique-row matrix.
+    """Indirected multi-query score over a unique-row matrix.
 
-    uniq uint32 [U, W] (from ``gather_rows``); indir int32 [Q, nb, L]
-    (index into uniq per term); mask int32 [Q, nb, L] -> int32
-    [Q, nb, W, 32].
+    uniq uint32 [U, lines * 128] (from ``gather_rows``, or host-gathered
+    rows at lane 0); indir int32 [Q, nb, L] (index into uniq per term);
+    mask int32 [Q, nb, L]; optional acc int32 [Q, nb, ws, 32] ->
+    int32 [Q, nb, ws, 32].
 
-    The [U, word_block] block's index map depends only on the word axis,
-    so the Pallas pipeline re-DMAs it ONLY when the word tile changes —
-    every (query, block) cell of a word tile scores against the same
-    resident VMEM copy, which is where the cross-query arena-tile reuse
-    the fused kernel lacks comes from.
+    The [U, 128] block's index map depends only on the word axis, so the
+    pipeline re-DMAs it ONLY when the word tile changes — every (query,
+    block) cell of a word tile scores against the same resident VMEM copy.
+    The caller keeps U within VMEM (``repro.kernels.ops``).
     """
-    U, W = uniq.shape
+    U = uniq.shape[0]
     Q, nb, L = indir.shape
-    n_planes = _num_planes(L)
+    lines = max(1, ws // LANES)
+    tw = min(ws, LANES)
+    out_spec = pl.BlockSpec((1, 1, tw, 32),
+                            lambda iw, iq, ib, ind, msk: (iq, ib, iw, 0))
+    in_specs = [pl.BlockSpec((U, LANES),
+                             lambda iw, iq, ib, ind, msk: (0, iw))]
+    operands = [indir, mask, uniq]
+    if acc is not None:
+        in_specs.append(out_spec)
+        operands.append(acc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(W // word_block, Q, nb),
-        in_specs=[
-            pl.BlockSpec((U, word_block),
-                         lambda iw, iq, ib, ind, msk: (0, iw)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, word_block, 32),
-                               lambda iw, iq, ib, ind, msk: (iq, ib, iw, 0)),
+        grid=(lines, Q, nb),
+        in_specs=in_specs,
+        out_specs=out_spec,
     )
-    kernel = functools.partial(_dedup_score_kernel, n_planes=n_planes,
-                               n_terms=L)
+    kernel = functools.partial(_dedup_score_kernel, n_planes=_num_planes(L),
+                               n_terms=L, ws=ws, has_acc=acc is not None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, nb, W, 32), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((Q, nb, ws, 32), jnp.int32),
         interpret=interpret,
-    )(indir, mask, uniq)
-
-
-# --------------------------------------------------------------------------
-# 5. fused-decode kernels: score straight off the rowdict-compressed arena
-# --------------------------------------------------------------------------
-#
-# A rowdict-coded shard lives in HBM as (dict_rows uint32 [D, W], refs int32
-# [R]) with D << R — the DeviceTileCache stages that pair instead of the
-# expanded tile, shrinking the HBM working set by the shard's ratio. The
-# kernels below decode by ONE extra scalar indirection in the BlockSpec
-# index map: where the raw kernels DMA ``arena[row]``, these DMA
-# ``dict[refs[row]]``. refs ride the scalar-prefetch channel (SMEM), so
-# rows decompress on the way HBM->VMEM — no expanded tile ever exists in
-# HBM, and effective gather bandwidth multiplies by R/D when queries hit
-# duplicate rows. Bit-identical to the raw kernels by construction
-# (dict[refs[row]] == arena[row]); property-tested in
-# tests/test_compression.py.
-
-
-def gather_rows_compressed(
-    dict_rows: jnp.ndarray,
-    refs: jnp.ndarray,
-    uniq_idx: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Fused decode+gather: (dict uint32 [D, W], refs int32 [R], uniq_idx
-    int32 [U]) -> uint32 [U, W] with out[u] = dict[refs[uniq_idx[u]]].
-
-    The compressed twin of ``gather_rows``: feeds ``dedup_score``
-    unchanged. The double indirection collapses at grid-index time —
-    both lookups are scalar reads, the DMA itself moves one dict row
-    tile, so duplicate rows ACROSS the unique set still cost one dict
-    slot each in cache-resident HBM."""
-    D, W = dict_rows.shape
-    U = uniq_idx.shape[0]
-
-    def kernel(idx_ref, refs_ref, dict_ref, out_ref):
-        del idx_ref, refs_ref            # consumed by the index map
-        out_ref[...] = dict_ref[...]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(W // word_block, U),
-        in_specs=[
-            pl.BlockSpec((1, word_block),
-                         lambda iw, iu, idx, refs: (refs[idx[iu]], iw)),
-        ],
-        out_specs=pl.BlockSpec((1, word_block),
-                               lambda iw, iu, idx, refs: (iu, iw)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((U, W), jnp.uint32),
-        interpret=interpret,
-    )(uniq_idx, refs, dict_rows)
-
-
-def _lookup_multi_comp_kernel(idx_ref, mask_ref, refs_ref, arena_ref,
-                              out_ref, planes_ref, *, n_planes: int,
-                              q_axis: int = 1, b_axis: int = 2):
-    # Same body as _lookup_multi_kernel; refs_ref is consumed by the
-    # BlockSpec index map (the decode), not by the compute.
-    del refs_ref
-    _lookup_multi_kernel(idx_ref, mask_ref, arena_ref, out_ref, planes_ref,
-                         n_planes=n_planes, q_axis=q_axis, b_axis=b_axis)
-
-
-def lookup_score_multi_compressed(
-    dict_rows: jnp.ndarray,
-    refs: jnp.ndarray,
-    rows_idx: jnp.ndarray,
-    mask: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    grid_order: str = "wq",
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Decode-in-the-loop variant of ``lookup_score_multi``: (dict uint32
-    [D, W], refs int32 [R], rows_idx int32 [Q, nb, L], mask int32
-    [Q, nb, L]) -> int32 [Q, nb, W, 32], scoring ``dict[refs[row]]``
-    where the raw kernel scores ``arena[row]``. Duplicate rows within AND
-    across queries resolve to the same dict slot, so repeated terms hit
-    tiles the pipeline already has in flight instead of new HBM traffic."""
-    D, W = dict_rows.shape
-    Q, nb, L = rows_idx.shape
-    n_planes = _num_planes(L)
-    if grid_order == "wq":
-        grid = (W // word_block, Q, nb, L)
-        arena_map = (lambda iw, iq, ib, il, idx, msk, refs:
-                     (refs[idx[iq, ib, il]], iw))
-        out_map = lambda iw, iq, ib, il, idx, msk, refs: (iq, ib, iw, 0)
-        q_axis, b_axis = 1, 2
-    elif grid_order == "qw":
-        grid = (Q, nb, W // word_block, L)
-        arena_map = (lambda iq, ib, iw, il, idx, msk, refs:
-                     (refs[idx[iq, ib, il]], iw))
-        out_map = lambda iq, ib, iw, il, idx, msk, refs: (iq, ib, iw, 0)
-        q_axis, b_axis = 0, 1
-    else:
-        raise ValueError(f"unknown grid_order {grid_order!r}; "
-                         f"one of {GRID_ORDERS}")
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, word_block), arena_map)],
-        out_specs=pl.BlockSpec((1, 1, word_block, 32), out_map),
-        scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
-    )
-    kernel = functools.partial(_lookup_multi_comp_kernel, n_planes=n_planes,
-                               q_axis=q_axis, b_axis=b_axis)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, nb, W, 32), jnp.int32),
-        interpret=interpret,
-    )(rows_idx, mask, refs, dict_rows)
-
-
-def _lookup_blocks_comp_kernel(idx_ref, mask_ref, refs_ref, arena_ref,
-                               out_ref, planes_ref, *, n_planes: int):
-    del refs_ref
-    _lookup_blocks_kernel(idx_ref, mask_ref, arena_ref, out_ref, planes_ref,
-                          n_planes=n_planes)
-
-
-def lookup_score_blocks_compressed(
-    dict_rows: jnp.ndarray,
-    refs: jnp.ndarray,
-    rows_idx: jnp.ndarray,
-    mask: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Decode-in-the-loop variant of ``lookup_score_blocks`` (single-query
-    compact hot loop): int32 [nb, W, 32] over ``dict[refs[row]]``."""
-    D, W = dict_rows.shape
-    nb, L = rows_idx.shape
-    n_planes = _num_planes(L)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(W // word_block, nb, L),
-        in_specs=[
-            pl.BlockSpec((1, word_block),
-                         lambda iw, ib, il, idx, msk, refs:
-                         (refs[idx[ib, il]], iw)),
-        ],
-        out_specs=pl.BlockSpec((1, word_block, 32),
-                               lambda iw, ib, il, idx, msk, refs:
-                               (ib, iw, 0)),
-        scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
-    )
-    kernel = functools.partial(_lookup_blocks_comp_kernel, n_planes=n_planes)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, W, 32), jnp.int32),
-        interpret=interpret,
-    )(rows_idx, mask, refs, dict_rows)
-
-
-# --------------------------------------------------------------------------
-# 6. chunked accumulator kernels: branch-and-bound pruned scoring
-# --------------------------------------------------------------------------
-#
-# Threshold/top-k queries do not need every term scored before blocks can
-# be discarded: after a PREFIX of the terms, any block whose best-possible
-# final score (running count + terms remaining) cannot reach the required
-# cutoff is dead. The chunked variants below score one term CHUNK and fold
-# the partial counts into a persistent per-(query, block) running-count
-# buffer ``acc`` — the executor (repro.core.query.run_paged_pruned) calls
-# them once per surviving (chunk, shard) visit and derives the per-block
-# survivor mask host-side from the block max of the returned buffer. All
-# three fused forms exist: raw (tile-resident), dedup (host-gathered
-# unique chunk rows — the out-of-core I/O saver), and _comp (fused decode
-# off the rowdict pair). Bit-identity with the unchunked kernels is by
-# construction: the sum over chunks telescopes into the full-term sum.
-
-
-def _chunk_dedup_kernel(indir_ref, mask_ref, uniq_ref, acc_ref, out_ref, *,
-                        n_planes: int, n_terms: int):
-    iq = pl.program_id(1)
-    ib = pl.program_id(2)
-    wb = uniq_ref.shape[1]
-
-    def add_term(il, planes):
-        u = indir_ref[iq, ib, il]
-        row = (uniq_ref[pl.ds(u, 1), :][0]
-               * mask_ref[iq, ib, il].astype(jnp.uint32))
-        carry = row
-        nxt = []
-        for j in range(n_planes):
-            new_carry = planes[j] & carry
-            nxt.append(planes[j] ^ carry)
-            carry = new_carry
-        return tuple(nxt)
-
-    planes = tuple(jnp.zeros((wb,), jnp.uint32) for _ in range(n_planes))
-    planes = jax.lax.fori_loop(0, n_terms, add_term, planes)
-
-    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-    acc = acc_ref[0, 0]
-    for j in range(n_planes):
-        bits = ((planes[j][:, None] >> shifts) & jnp.uint32(1))
-        acc += bits.astype(jnp.int32) << j
-    out_ref[0, 0] = acc
-
-
-def chunk_dedup_score(
-    uniq: jnp.ndarray,
-    indir: jnp.ndarray,
-    mask: jnp.ndarray,
-    acc: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """``dedup_score`` over one term chunk, accumulated into ``acc``.
-
-    uniq uint32 [U, W] (the chunk's unique rows, host-gathered so only
-    the touched rows were ever read); indir/mask int32 [Q, nb, Lc];
-    acc int32 [Q, nb, W, 32] (running counts) -> int32 [Q, nb, W, 32]
-    with out = acc + chunk partial counts."""
-    U, W = uniq.shape
-    Q, nb, L = indir.shape
-    n_planes = _num_planes(L)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(W // word_block, Q, nb),
-        in_specs=[
-            pl.BlockSpec((U, word_block),
-                         lambda iw, iq, ib, ind, msk: (0, iw)),
-            pl.BlockSpec((1, 1, word_block, 32),
-                         lambda iw, iq, ib, ind, msk: (iq, ib, iw, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, word_block, 32),
-                               lambda iw, iq, ib, ind, msk: (iq, ib, iw, 0)),
-    )
-    kernel = functools.partial(_chunk_dedup_kernel, n_planes=n_planes,
-                               n_terms=L)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, nb, W, 32), jnp.int32),
-        interpret=interpret,
-    )(indir, mask, uniq, acc)
-
-
-def _chunk_multi_kernel(idx_ref, mask_ref, arena_ref, acc_ref, out_ref,
-                        planes_ref, *, n_planes: int):
-    il = pl.program_id(3)
-    n_l = pl.num_programs(3)
-
-    @pl.when(il == 0)
-    def _init():
-        planes_ref[...] = jnp.zeros_like(planes_ref)
-
-    iq = pl.program_id(1)
-    ib = pl.program_id(2)
-    row = arena_ref[0, :] * mask_ref[iq, ib, il].astype(jnp.uint32)
-    carry = row
-    for j in range(n_planes):
-        new_carry = planes_ref[j, :] & carry
-        planes_ref[j, :] = planes_ref[j, :] ^ carry
-        carry = new_carry
-
-    @pl.when(il == n_l - 1)
-    def _expand():
-        shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-        acc = acc_ref[0, 0]
-        for j in range(n_planes):
-            bits = ((planes_ref[j, :][:, None] >> shifts) & jnp.uint32(1))
-            acc += bits.astype(jnp.int32) << j
-        out_ref[0, 0] = acc
-
-
-def chunk_lookup_score_multi(
-    arena: jnp.ndarray,
-    rows_idx: jnp.ndarray,
-    mask: jnp.ndarray,
-    acc: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """``lookup_score_multi`` over one term chunk, accumulated into ``acc``.
-
-    Used by the pruned executor when the shard's full tile is already
-    resident (promoted / cached) — the chunk's rows stream straight out of
-    the staged tile, no host gather. rows_idx/mask int32 [Q, nb, Lc];
-    acc int32 [Q, nb, W, 32] -> acc + chunk counts."""
-    R, W = arena.shape
-    Q, nb, L = rows_idx.shape
-    n_planes = _num_planes(L)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(W // word_block, Q, nb, L),
-        in_specs=[
-            pl.BlockSpec((1, word_block),
-                         lambda iw, iq, ib, il, idx, msk:
-                         (idx[iq, ib, il], iw)),
-            pl.BlockSpec((1, 1, word_block, 32),
-                         lambda iw, iq, ib, il, idx, msk: (iq, ib, iw, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, word_block, 32),
-                               lambda iw, iq, ib, il, idx, msk:
-                               (iq, ib, iw, 0)),
-        scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
-    )
-    kernel = functools.partial(_chunk_multi_kernel, n_planes=n_planes)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, nb, W, 32), jnp.int32),
-        interpret=interpret,
-    )(rows_idx, mask, arena, acc)
-
-
-def _chunk_multi_comp_kernel(idx_ref, mask_ref, refs_ref, arena_ref, acc_ref,
-                             out_ref, planes_ref, *, n_planes: int):
-    del refs_ref                 # consumed by the BlockSpec index map
-    _chunk_multi_kernel(idx_ref, mask_ref, arena_ref, acc_ref, out_ref,
-                        planes_ref, n_planes=n_planes)
-
-
-def chunk_lookup_score_multi_compressed(
-    dict_rows: jnp.ndarray,
-    refs: jnp.ndarray,
-    rows_idx: jnp.ndarray,
-    mask: jnp.ndarray,
-    acc: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Fused-decode twin of ``chunk_lookup_score_multi``: the chunk's rows
-    resolve ``dict[refs[row]]`` inside the gather, so a dict-coded shard
-    scores chunks straight off its compressed (dict, refs) HBM pair."""
-    D, W = dict_rows.shape
-    Q, nb, L = rows_idx.shape
-    n_planes = _num_planes(L)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(W // word_block, Q, nb, L),
-        in_specs=[
-            pl.BlockSpec((1, word_block),
-                         lambda iw, iq, ib, il, idx, msk, refs:
-                         (refs[idx[iq, ib, il]], iw)),
-            pl.BlockSpec((1, 1, word_block, 32),
-                         lambda iw, iq, ib, il, idx, msk, refs:
-                         (iq, ib, iw, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, word_block, 32),
-                               lambda iw, iq, ib, il, idx, msk, refs:
-                               (iq, ib, iw, 0)),
-        scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
-    )
-    kernel = functools.partial(_chunk_multi_comp_kernel, n_planes=n_planes)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, nb, W, 32), jnp.int32),
-        interpret=interpret,
-    )(rows_idx, mask, refs, dict_rows, acc)
-
-
-def lookup_score(
-    arena: jnp.ndarray,
-    rows_idx: jnp.ndarray,
-    mask: jnp.ndarray,
-    *,
-    word_block: int = DEFAULT_WORD_BLOCK,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Fused gather+score: (arena uint32 [R, W], rows_idx int32 [L],
-    mask int32 [L]) -> int32 [W, 32]. W % word_block == 0.
-
-    The row index per grid step comes from scalar prefetch, so each [1, bw]
-    arena tile is DMA'd HBM->VMEM exactly when needed and the gathered [L, W]
-    intermediate never exists.
-    """
-    R, W = arena.shape
-    L = rows_idx.shape[0]
-    n_planes = _num_planes(L)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(W // word_block, L),
-        in_specs=[
-            pl.BlockSpec((1, word_block), lambda iw, il, idx, msk: (idx[il], iw)),
-        ],
-        out_specs=pl.BlockSpec((word_block, 32), lambda iw, il, idx, msk: (iw, 0)),
-        scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
-    )
-    kernel = functools.partial(_lookup_kernel, n_planes=n_planes)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((W, 32), jnp.int32),
-        interpret=interpret,
-    )(rows_idx, mask, arena)
+    )(*operands)

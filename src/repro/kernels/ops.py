@@ -13,10 +13,12 @@ tuned configuration reaches every call site without baked-in constants.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import bitslice_score as _k
 from . import ref as _ref
@@ -72,123 +74,266 @@ def bitslice_score(
     return out[:W].reshape(-1)
 
 
+# --------------------------------------------------------------------------
+# lane-packed arenas (the device form the row-gather kernels read)
+# --------------------------------------------------------------------------
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["lines"], meta_fields=["rows", "words"])
+@dataclasses.dataclass(frozen=True)
+class PackedArena:
+    """A [rows, words] uint32 arena held as 128-lane lines (uint32
+    [N, 128], see ``bitslice_score.row_geometry``) — the form the device
+    tile cache stages, so the row-gather kernels never relayout it. It
+    passes through ``jax.jit`` as a pytree; ``shape`` is the logical
+    one."""
+    lines: jnp.ndarray
+    rows: int
+    words: int
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.rows, self.words)
+
+    @property
+    def dtype(self):
+        return self.lines.dtype
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.lines.nbytes)
+
+
+def pack_lines(arena, xp=np):
+    """Logical arena [R, W] -> uint32 lines [N, 128] (N a multiple of 8),
+    with ``xp`` numpy (host staging) or jax.numpy (traced). A plain reshape
+    when W is a power of two and R fills whole tiles."""
+    R, W = arena.shape
+    ws, per_line, _ = _k.row_geometry(W)
+    rows = -(-R // per_line) * per_line
+    if (rows, ws) != (R, W):
+        arena = xp.pad(arena, ((0, rows - R), (0, ws - W)))
+    lines = arena.reshape(-1, _k.LANES)
+    n = -(-lines.shape[0] // _k.SUBLANES) * _k.SUBLANES
+    if n != lines.shape[0]:
+        lines = xp.pad(lines, ((0, n - lines.shape[0]), (0, 0)))
+    return lines
+
+
+def pack_arena(arena) -> PackedArena:
+    """Any [R, W] arena (host or device) -> PackedArena (traceable)."""
+    if isinstance(arena, PackedArena):
+        return arena
+    R, W = arena.shape
+    return PackedArena(pack_lines(jnp.asarray(arena), jnp), R, W)
+
+
+def take_rows(arena, rows: jnp.ndarray) -> jnp.ndarray:
+    """``arena[rows]`` for a logical or packed arena: uint32 [..., W]."""
+    if not isinstance(arena, PackedArena):
+        return arena[rows]
+    ws, per_line, lines = _k.row_geometry(arena.words)
+    if per_line > 1:
+        g = arena.lines[rows // per_line]               # [..., 128]
+        g = g.reshape(*rows.shape, per_line, ws)
+        seg = (rows % per_line)[..., None, None]
+        g = jnp.take_along_axis(g, seg, axis=-2)[..., 0, :]
+    else:
+        g = arena.lines[rows[..., None] * lines + jnp.arange(lines)]
+        g = g.reshape(*rows.shape, ws)
+    return g[..., :arena.words]
+
+
+# --------------------------------------------------------------------------
+# scalar- and vector-memory bounds of one kernel call
+# --------------------------------------------------------------------------
+
+# Entries per scalar-prefetched int32 table ([Q, nb, L] indices or masks)
+# in one pallas_call. TPU scalar memory is 1 MiB and holds both tables;
+# larger dispatches are split over queries, blocks and terms.
+SMEM_TABLE_ENTRIES = 1 << 15
+
+# VMEM bytes the dedup kernel's resident [U, 128] unique-row tile may take
+# (double-buffered). Batches with more unique rows score the gathered
+# matrix through the lookup kernel instead, which streams it from HBM.
+DEDUP_VMEM_BYTES = 4 << 20
+
+
+def _over_cells(call, idx, mask, acc=None):
+    """Run ``call(idx, mask, acc) -> int32 [q, b, ws, 32]`` over pieces of
+    the [Q, nb, L] tables that fit ``SMEM_TABLE_ENTRIES``. Term pieces
+    add up (or thread ``acc``), so the result is exact."""
+    Q, nb, L = idx.shape
+    if Q * nb * L <= SMEM_TABLE_ENTRIES:
+        return call(idx, mask, acc)
+    lc = min(L, SMEM_TABLE_ENTRIES)
+    bc = min(nb, max(1, SMEM_TABLE_ENTRIES // lc))
+    qc = min(Q, max(1, SMEM_TABLE_ENTRIES // (bc * lc)))
+    rows = []
+    for q0 in range(0, Q, qc):
+        cols = []
+        for b0 in range(0, nb, bc):
+            cell = (slice(q0, q0 + qc), slice(b0, b0 + bc))
+            run = None if acc is None else acc[cell]
+            for l0 in range(0, L, lc):
+                piece = cell + (slice(l0, l0 + lc),)
+                if acc is not None:
+                    run = call(idx[piece], mask[piece], run)
+                else:
+                    part = call(idx[piece], mask[piece], None)
+                    run = part if run is None else run + part
+            cols.append(run)
+        rows.append(jnp.concatenate(cols, axis=1))
+    return jnp.concatenate(rows, axis=0)
+
+
+def _lookup(arena, idx, mask, acc, interpret, grid_order="wq"):
+    """Fused gather+score of a packed arena: int32 [Q, nb, ws, 32]."""
+    ws, per_line, _ = _k.row_geometry(arena.words)
+    return _over_cells(
+        lambda i, m, a: _k.lookup_score_multi(
+            arena.lines, i, m, a, per_line=per_line, ws=ws,
+            grid_order=grid_order, interpret=interpret),
+        idx.astype(jnp.int32), mask.astype(jnp.int32), acc)
+
+
+def _score_unique(uniq, ws, indir, mask, acc, interpret):
+    """Score a unique-row matrix uint32 [U, lines * 128] through ``indir``:
+    the VMEM-resident dedup kernel when it fits, else the lookup kernel
+    reading the matrix as an arena of one row per line."""
+    indir, mask = indir.astype(jnp.int32), mask.astype(jnp.int32)
+    U = uniq.shape[0]
+    if U * uniq.shape[1] * 4 * 2 <= DEDUP_VMEM_BYTES:
+        return _over_cells(
+            lambda i, m, a: _k.dedup_score(uniq, i, m, a, ws=ws,
+                                           interpret=interpret),
+            indir, mask, acc)
+    return _over_cells(
+        lambda i, m, a: _k.lookup_score_multi(
+            uniq.reshape(-1, _k.LANES), i, m, a, per_line=1, ws=ws,
+            interpret=interpret),
+        indir, mask, acc)
+
+
+def _lane_rows(rows: jnp.ndarray) -> jnp.ndarray:
+    """Logical rows uint32 [U, W] -> [U_pad, lines * 128] at lane 0 (the
+    unique-row matrix layout), U padded to a multiple of 8."""
+    U, W = rows.shape
+    lines = _k.row_geometry(W)[2]
+    u_pad = -(-U // _k.SUBLANES) * _k.SUBLANES
+    return jnp.pad(rows, ((0, u_pad - U), (0, lines * _k.LANES - W)))
+
+
+def _slots(out: jnp.ndarray, W: int) -> jnp.ndarray:
+    """int32 [Q, nb, ws, 32] -> [Q, nb * W * 32] in slot order."""
+    return out[:, :, :W].reshape(out.shape[0], -1)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
 def bitslice_lookup_score(
-    arena: jnp.ndarray,
+    arena,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
     interpret: bool | None = None,
     word_block: int | None = None,
 ) -> jnp.ndarray:
-    """Fused gather+score from the arena: -> int32 [W * 32]."""
-    if interpret is None:
-        interpret = _use_interpret()
-    R, W = arena.shape
-    wb = _word_block(W, word_block)
-    arena_p = _pad_axis(arena, 1, wb)
-    out = _k.lookup_score(
-        arena_p, rows_idx.astype(jnp.int32), mask.astype(jnp.int32),
-        word_block=wb, interpret=interpret)
-    return out[:W].reshape(-1)
+    """Fused gather+score from the arena ([R, W] or PackedArena; rows_idx
+    and mask int32 [L]) -> int32 [W * 32]. The lookup family reads whole
+    128-lane lines, so ``word_block`` only steers the vertical/unpack
+    kernels and is accepted here for a uniform call surface."""
+    return bitslice_lookup_score_multi(arena, rows_idx[None, None],
+                                       mask[None, None],
+                                       interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
 def bitslice_lookup_score_blocks(
-    arena: jnp.ndarray,
+    arena,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
     interpret: bool | None = None,
     word_block: int | None = None,
 ) -> jnp.ndarray:
-    """Multi-block fused gather+score: (arena [R, W], rows_idx [nb, L],
-    mask [nb, L]) -> int32 [nb * W * 32] in (block, word, bit) slot order."""
-    if interpret is None:
-        interpret = _use_interpret()
-    R, W = arena.shape
-    wb = _word_block(W, word_block)
-    arena_p = _pad_axis(arena, 1, wb)
-    out = _k.lookup_score_blocks(
-        arena_p, rows_idx.astype(jnp.int32), mask.astype(jnp.int32),
-        word_block=wb, interpret=interpret)
-    return out[:, :W].reshape(-1)
+    """Multi-block fused gather+score: (arena, rows_idx [nb, L], mask
+    [nb, L]) -> int32 [nb * W * 32] in (block, word, bit) slot order."""
+    return bitslice_lookup_score_multi(arena, rows_idx[None], mask[None],
+                                       interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block",
                                              "grid_order"))
 def bitslice_lookup_score_multi(
-    arena: jnp.ndarray,
+    arena,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
     interpret: bool | None = None,
     word_block: int | None = None,
     grid_order: str = "wq",
 ) -> jnp.ndarray:
-    """Multi-query multi-block fused gather+score: (arena [R, W], rows_idx
-    [Q, nb, L], mask [Q, nb, L]) -> int32 [Q, nb * W * 32], each query in
-    (block, word, bit) slot order — the serving batch hot path."""
+    """Multi-query multi-block fused gather+score: (arena [R, W] or
+    PackedArena, rows_idx [Q, nb, L], mask [Q, nb, L]) -> int32
+    [Q, nb * W * 32], each query in (block, word, bit) slot order — the
+    serving batch hot path."""
     if interpret is None:
         interpret = _use_interpret()
-    R, W = arena.shape
-    Q = rows_idx.shape[0]
-    wb = _word_block(W, word_block)
-    arena_p = _pad_axis(arena, 1, wb)
-    out = _k.lookup_score_multi(
-        arena_p, rows_idx.astype(jnp.int32), mask.astype(jnp.int32),
-        word_block=wb, grid_order=grid_order, interpret=interpret)
-    return out[:, :, :W].reshape(Q, -1)
+    arena = pack_arena(arena)
+    return _slots(_lookup(arena, rows_idx, mask, None, interpret,
+                          grid_order), arena.words)
+
+
+def _gather_unique(arena: PackedArena, uniq_rows, interpret):
+    """uniq_rows int32 [U] or [U, k] -> unique-row matrix (k rows ANDed)."""
+    ws, per_line, _ = _k.row_geometry(arena.words)
+    uniq_rows = uniq_rows.astype(jnp.int32)
+    cols = uniq_rows[:, None] if uniq_rows.ndim == 1 else uniq_rows
+    U = cols.shape[0]
+    cols = jnp.pad(cols, ((0, (-U) % _k.SUBLANES), (0, 0)))
+    uniq = None
+    for j in range(cols.shape[1]):
+        g = _k.gather_rows(arena.lines, cols[:, j], per_line=per_line,
+                           ws=ws, interpret=interpret)
+        uniq = g if uniq is None else uniq & g
+    return uniq, ws
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
 def bitslice_lookup_score_dedup(
-    arena: jnp.ndarray,
+    arena,
     uniq_rows: jnp.ndarray,
     indir: jnp.ndarray,
     mask: jnp.ndarray,
     interpret: bool | None = None,
     word_block: int | None = None,
 ) -> jnp.ndarray:
-    """Row-dedup batched gather+score: (arena [R, W], uniq_rows [U] or
-    [U, k], indir [Q, nb, L], mask [Q, nb, L]) -> int32 [Q, nb * W * 32].
+    """Row-dedup batched gather+score: (arena, uniq_rows [U] or [U, k],
+    indir [Q, nb, L], mask [Q, nb, L]) -> int32 [Q, nb * W * 32].
 
     Two kernels: ``gather_rows`` streams each unique arena row from HBM
-    exactly once into a compact [U, W] matrix; ``dedup_score`` accumulates
-    every query through the indirection against that matrix (resident in
-    VMEM per word tile). Arena DMA traffic is U row tiles instead of the
-    fused path's Q*nb*L — the win scales with batch row overlap. Semantics
-    == ``bitslice_lookup_score_multi(arena, uniq_rows[indir], mask)``,
+    exactly once into a compact unique-row matrix; ``dedup_score``
+    accumulates every query through the indirection against that matrix
+    (resident in VMEM per word tile). Arena DMA traffic is U row tiles
+    instead of the fused path's Q*nb*L. Semantics ==
+    ``bitslice_lookup_score_multi(arena, uniq_rows[indir], mask)``,
     property-tested bit-identical.
 
     For k>1 indexes ``uniq_rows`` is [U, k]: each unique entry is a
     (row-set) tuple whose k gathered rows are AND-reduced on device before
-    scoring — dedup over AND'd tuples, so shared row-SETS between queries
-    (not just shared single rows) collapse to one gather + one AND each.
+    scoring, so shared row-SETS between queries collapse to one gather +
+    one AND each.
     """
     if interpret is None:
         interpret = _use_interpret()
-    R, W = arena.shape
-    Q = indir.shape[0]
-    wb = _word_block(W, word_block)
-    arena_p = _pad_axis(arena, 1, wb)
-    uniq_rows = uniq_rows.astype(jnp.int32)
-    if uniq_rows.ndim == 1:
-        uniq = _k.gather_rows(arena_p, uniq_rows, word_block=wb,
-                              interpret=interpret)
-    else:
-        uniq = _k.gather_rows(arena_p, uniq_rows[:, 0], word_block=wb,
-                              interpret=interpret)
-        for j in range(1, uniq_rows.shape[1]):
-            uniq = uniq & _k.gather_rows(arena_p, uniq_rows[:, j],
-                                         word_block=wb, interpret=interpret)
-    out = _k.dedup_score(uniq, indir.astype(jnp.int32),
-                         mask.astype(jnp.int32), word_block=wb,
-                         interpret=interpret)
-    return out[:, :, :W].reshape(Q, -1)
+    arena = pack_arena(arena)
+    uniq, ws = _gather_unique(arena, uniq_rows, interpret)
+    return _slots(_score_unique(uniq, ws, indir, mask, None, interpret),
+                  arena.words)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block",
                                              "grid_order"))
 def bitslice_lookup_score_multi_comp(
-    dict_rows: jnp.ndarray,
+    dict_rows,
     refs: jnp.ndarray,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
@@ -198,25 +343,17 @@ def bitslice_lookup_score_multi_comp(
 ) -> jnp.ndarray:
     """``bitslice_lookup_score_multi`` over a rowdict-compressed arena:
     (dict [D, W], refs [R], rows_idx [Q, nb, L], mask [Q, nb, L]) ->
-    int32 [Q, nb * W * 32]. Rows decode HBM->VMEM inside the kernel via
-    ``dict[refs[row]]`` — bit-identical to the raw path on the expanded
-    tile, moving D-dict-row working sets instead of R."""
-    if interpret is None:
-        interpret = _use_interpret()
-    D, W = dict_rows.shape
-    Q = rows_idx.shape[0]
-    wb = _word_block(W, word_block)
-    dict_p = _pad_axis(dict_rows, 1, wb)
-    out = _k.lookup_score_multi_compressed(
-        dict_p, refs.astype(jnp.int32), rows_idx.astype(jnp.int32),
-        mask.astype(jnp.int32), word_block=wb, grid_order=grid_order,
-        interpret=interpret)
-    return out[:, :, :W].reshape(Q, -1)
+    int32 [Q, nb * W * 32]. ``refs[rows_idx]`` is composed on the device
+    before the call, so the kernel gathers dict rows directly and the
+    refs table never rides scalar memory."""
+    return bitslice_lookup_score_multi(dict_rows, refs[rows_idx], mask,
+                                       interpret=interpret,
+                                       grid_order=grid_order)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
 def bitslice_lookup_score_blocks_comp(
-    dict_rows: jnp.ndarray,
+    dict_rows,
     refs: jnp.ndarray,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
@@ -224,21 +361,14 @@ def bitslice_lookup_score_blocks_comp(
     word_block: int | None = None,
 ) -> jnp.ndarray:
     """``bitslice_lookup_score_blocks`` over a rowdict-compressed arena:
-    single-query decode-in-the-loop scoring, int32 [nb * W * 32]."""
-    if interpret is None:
-        interpret = _use_interpret()
-    D, W = dict_rows.shape
-    wb = _word_block(W, word_block)
-    dict_p = _pad_axis(dict_rows, 1, wb)
-    out = _k.lookup_score_blocks_compressed(
-        dict_p, refs.astype(jnp.int32), rows_idx.astype(jnp.int32),
-        mask.astype(jnp.int32), word_block=wb, interpret=interpret)
-    return out[:, :W].reshape(-1)
+    int32 [nb * W * 32]."""
+    return bitslice_lookup_score_blocks(dict_rows, refs[rows_idx], mask,
+                                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
 def bitslice_lookup_score_dedup_comp(
-    dict_rows: jnp.ndarray,
+    dict_rows,
     refs: jnp.ndarray,
     uniq_rows: jnp.ndarray,
     indir: jnp.ndarray,
@@ -247,31 +377,10 @@ def bitslice_lookup_score_dedup_comp(
     word_block: int | None = None,
 ) -> jnp.ndarray:
     """``bitslice_lookup_score_dedup`` over a rowdict-compressed arena:
-    ``gather_rows_compressed`` decodes each unique row (or k>1 row-set,
-    AND-reduced) out of the dict on the way HBM->VMEM, then the identical
-    ``dedup_score`` indirection scores it. int32 [Q, nb * W * 32]."""
-    if interpret is None:
-        interpret = _use_interpret()
-    D, W = dict_rows.shape
-    Q = indir.shape[0]
-    wb = _word_block(W, word_block)
-    dict_p = _pad_axis(dict_rows, 1, wb)
-    refs = refs.astype(jnp.int32)
-    uniq_rows = uniq_rows.astype(jnp.int32)
-    if uniq_rows.ndim == 1:
-        uniq = _k.gather_rows_compressed(dict_p, refs, uniq_rows,
-                                         word_block=wb, interpret=interpret)
-    else:
-        uniq = _k.gather_rows_compressed(dict_p, refs, uniq_rows[:, 0],
-                                         word_block=wb, interpret=interpret)
-        for j in range(1, uniq_rows.shape[1]):
-            uniq = uniq & _k.gather_rows_compressed(
-                dict_p, refs, uniq_rows[:, j], word_block=wb,
-                interpret=interpret)
-    out = _k.dedup_score(uniq, indir.astype(jnp.int32),
-                         mask.astype(jnp.int32), word_block=wb,
-                         interpret=interpret)
-    return out[:, :, :W].reshape(Q, -1)
+    each unique row (or k>1 row-set) is gathered from the dict at
+    ``refs[uniq_rows]``. int32 [Q, nb * W * 32]."""
+    return bitslice_lookup_score_dedup(dict_rows, refs[uniq_rows], indir,
+                                       mask, interpret=interpret)
 
 
 # --------------------------------------------------------------------------
@@ -288,18 +397,20 @@ def bitslice_lookup_score_dedup_comp(
 
 def chunk_acc_init(q: int, nb: int, w: int,
                    word_block: int | None = None) -> jnp.ndarray:
-    """Fresh running-count buffer int32 [Q, nb, Wp, 32] with the word axis
-    pre-padded to the kernel tile multiple (stable across chunk calls)."""
-    wb = _word_block(w, word_block)
-    wp = w + ((-w) % wb)
-    return jnp.zeros((q, nb, wp, 32), jnp.int32)
+    """Fresh running-count buffer int32 [Q, nb, ws, 32] with the word axis
+    at the kernels' stored row width (stable across chunk calls)."""
+    ws, _, _ = _k.row_geometry(w)
+    return jnp.zeros((q, nb, ws, 32), jnp.int32)
 
 
 def chunk_acc_scores(acc: jnp.ndarray, w: int) -> jnp.ndarray:
     """Finished running counts -> int32 [Q, nb * W * 32] in the engine's
     (block, word, bit) slot order."""
-    q = acc.shape[0]
-    return acc[:, :, :w].reshape(q, -1)
+    return _slots(acc, w)
+
+
+def _with_max(out):
+    return out, jnp.max(out, axis=(2, 3))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
@@ -311,26 +422,21 @@ def bitslice_chunk_score_dedup(
     interpret: bool | None = None,
     word_block: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One term chunk against a host-gathered unique-row matrix.
+    """One term chunk against a gathered unique-row matrix.
 
     uniq uint32 [U, W] (only the chunk's touched rows were read from the
-    store — for k>1 the host pre-ANDs the row sets); indir/mask int32
-    [Q, nb, Lc]; acc int32 [Q, nb, Wp, 32]. Returns (acc + chunk counts,
-    per-block max int32 [Q, nb])."""
+    store — for k>1 the rows are pre-ANDed); indir/mask int32 [Q, nb, Lc];
+    acc int32 [Q, nb, ws, 32]. Returns (acc + chunk counts, per-block max
+    int32 [Q, nb])."""
     if interpret is None:
         interpret = _use_interpret()
-    U, W = uniq.shape
-    wb = _word_block(W, word_block)
-    uniq_p = _pad_axis(uniq, 1, wb)
-    out = _k.chunk_dedup_score(uniq_p, indir.astype(jnp.int32),
-                               mask.astype(jnp.int32), acc,
-                               word_block=wb, interpret=interpret)
-    return out, jnp.max(out, axis=(2, 3))
+    return _with_max(_score_unique(_lane_rows(uniq), acc.shape[2], indir,
+                                   mask, acc, interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
 def bitslice_chunk_score_multi(
-    arena: jnp.ndarray,
+    arena,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
     acc: jnp.ndarray,
@@ -342,18 +448,13 @@ def bitslice_chunk_score_multi(
     Returns (acc + chunk counts, per-block max int32 [Q, nb])."""
     if interpret is None:
         interpret = _use_interpret()
-    R, W = arena.shape
-    wb = _word_block(W, word_block)
-    arena_p = _pad_axis(arena, 1, wb)
-    out = _k.chunk_lookup_score_multi(
-        arena_p, rows_idx.astype(jnp.int32), mask.astype(jnp.int32), acc,
-        word_block=wb, interpret=interpret)
-    return out, jnp.max(out, axis=(2, 3))
+    return _with_max(_lookup(pack_arena(arena), rows_idx, mask, acc,
+                             interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "word_block"))
 def bitslice_chunk_score_multi_comp(
-    dict_rows: jnp.ndarray,
+    dict_rows,
     refs: jnp.ndarray,
     rows_idx: jnp.ndarray,
     mask: jnp.ndarray,
@@ -361,17 +462,11 @@ def bitslice_chunk_score_multi_comp(
     interpret: bool | None = None,
     word_block: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One term chunk fused-DECODED from a resident (dict, refs) pair —
-    the compressed promoted path. Returns (acc', per-block max)."""
-    if interpret is None:
-        interpret = _use_interpret()
-    D, W = dict_rows.shape
-    wb = _word_block(W, word_block)
-    dict_p = _pad_axis(dict_rows, 1, wb)
-    out = _k.chunk_lookup_score_multi_compressed(
-        dict_p, refs.astype(jnp.int32), rows_idx.astype(jnp.int32),
-        mask.astype(jnp.int32), acc, word_block=wb, interpret=interpret)
-    return out, jnp.max(out, axis=(2, 3))
+    """One term chunk gathered from a resident (dict, refs) pair at
+    ``refs[rows_idx]`` — the compressed promoted path. Returns (acc',
+    per-block max)."""
+    return bitslice_chunk_score_multi(dict_rows, refs[rows_idx], mask, acc,
+                                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -402,7 +497,7 @@ def gather_and_rows(arena: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
     This replaces the unpromoted path's host mmap reads once a tile is
     staged — the gather streams out of HBM and XLA fuses the AND into it,
     so post-promotion chunks never touch the host arena again."""
-    g = arena[rows]                               # [U, k, W]
+    g = take_rows(arena, rows)                    # [U, k, W]
     out = g[:, 0]
     for i in range(1, g.shape[1]):
         out = out & g[:, i]
@@ -415,7 +510,7 @@ def gather_and_rows_comp(dict_rows: jnp.ndarray, refs: jnp.ndarray,
     """``gather_and_rows`` against a resident (dict, refs) pair: the
     double gather decodes rowdict-coded rows on the fly, HBM traffic
     proportional to the dictionary instead of the expanded tile."""
-    g = dict_rows[refs[rows]]                     # [U, k, W]
+    g = take_rows(dict_rows, refs[rows])          # [U, k, W]
     out = g[:, 0]
     for i in range(1, g.shape[1]):
         out = out & g[:, i]
@@ -429,14 +524,13 @@ def bulk_query_chunk(nb: int, w: int, *, word_block: int | None = None,
 
     The bulk lane scores the whole query set against one resident tile in
     slabs of Qc queries; the dominant live buffer is the running-count
-    accumulator int32 [Qc, nb, Wp, 32], so Qc is chosen to keep that under
+    accumulator int32 [Qc, nb, ws, 32], so Qc is chosen to keep that under
     ``budget_bytes`` (a conservative stand-in for the VMEM/HBM slice the
     chunk kernels can hold). Rounded down to a power of two so every slab
     of a sweep shares one compiled kernel shape (the last slab is padded
     up, never down)."""
-    wb = _word_block(w, word_block)
-    wp = w + ((-w) % wb)
-    per_q = max(1, nb * wp * 32 * 4)
+    ws, _, _ = _k.row_geometry(w)
+    per_q = max(1, nb * ws * 32 * 4)
     q = max(int(floor), int(budget_bytes) // per_q)
     q = 1 << (q.bit_length() - 1)                 # pow2 floor
     return int(min(int(cap), max(int(floor), q)))

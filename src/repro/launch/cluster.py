@@ -111,9 +111,6 @@ class WorkerCluster:
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (_repo_src_dir(), env.get("PYTHONPATH")) if p)
-        # workers only score small CPU batches; keep child JAX off any
-        # accelerator the parent may be using
-        env.setdefault("JAX_PLATFORMS", "cpu")
         self.procs[node] = subprocess.Popen(
             cmd, env=env, stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT,

@@ -141,7 +141,7 @@ def run_cobs_cell(mesh, mesh_name: str, n_docs: int = 102_400,
                                          jnp.uint32)
         body = dist._shard_body(topk=32)
         in_specs, out_specs = dist._specs(32)
-        from ..compat import shard_map
+        from jax import shard_map
         fn = shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
         terms = jax.ShapeDtypeStruct((batch_queries, ell, 2), jnp.uint32)

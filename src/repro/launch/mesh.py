@@ -11,16 +11,15 @@ over ("pod", "data") (FSDP on "data") and tensor/expert dims over "model".
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
-
-from ..compat import make_mesh as _compat_make_mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (keeps the historical shard_map/pjit behaviour stable
-    across jax versions)."""
-    return _compat_make_mesh(shape, axes)
+    """jax.make_mesh with explicit Auto axis types (the shard_map/pjit
+    behaviour the engines were written against)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -194,19 +194,25 @@ def make_multihost_frontend(store_dir, *, hosts: int, replication: int,
     manifest rows, open each host's sub-store, wire the hedging frontend
     (per-shard dispatches overlap through ``scatter_threads`` in
     wall-clock mode), and optionally mark hosts down (their shards fail
-    over to replicas)."""
+    over to replicas). Host i's tiles live on ``jax.devices()[i]``
+    (round-robin when there are fewer devices than hosts), so one process
+    serves an index sharded over every chip it holds."""
+    import jax
+
     from ..index import ShardPlacement
 
     nodes = [f"host{i}" for i in range(hosts)]
     placement = ShardPlacement.for_store(store_dir, nodes,
                                          replication=min(replication, hosts))
     held = placement.replica_assignment()
+    devices = jax.devices()
     workers = {n: ShardWorker(n, store_dir, held[n],
                               tile_cache_bytes=tile_cache_bytes,
+                              device=devices[i % len(devices)],
                               word_block=word_block, pruned=pruned,
                               prune_chunk=prune_chunk,
                               prune_min_rate=prune_min_rate)
-               for n in nodes if held[n]}
+               for i, n in enumerate(nodes) if held[n]}
     frontend = Frontend(workers, placement, FrontendConfig(
         max_batch=max_batch, max_wait_s=max_wait_s,
         hedge_after_s=hedge_after_s, hedge_auto=hedge_auto,
@@ -562,6 +568,8 @@ def main() -> None:
                          "(replay with benchmarks/trace_report.py)")
     ap.add_argument("--no-warmup", action="store_true")
     args = ap.parse_args()
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.hedge_after_ms == "auto":
         hedge_after_ms, hedge_auto = 50.0, True
     else:
@@ -725,10 +733,11 @@ def main() -> None:
     wall = time.perf_counter() - t0
 
     responses = server.pop_responses()
-    correct = total = 0
+    correct = total = not_ok = 0
     for rid, o in zip(ids, origin):
         r = responses.get(rid)
         if r is None or r.status != Status.OK:
+            not_ok += 1
             continue
         hit_ids = set(r.result.doc_ids.tolist())
         correct += (o in hit_ids) if o >= 0 else (len(hit_ids) == 0)
@@ -737,7 +746,8 @@ def main() -> None:
     print(f"mode={args.mode} served {snap.served} queries in {wall:.2f}s "
           f"-> {snap.served / wall:.0f} qps")
     print(snap.report())
-    print(f"accuracy vs ground truth: {correct}/{total}")
+    print(f"accuracy vs ground truth: {correct}/{total}"
+          + (f" ({not_ok} queries not OK)" if not_ok else ""))
 
     if args.bulk:
         # inline sweep: same lane, synchronous drain — the report's
@@ -750,6 +760,8 @@ def main() -> None:
 
     if args.workers:
         server.close()               # drop the worker channel pool
+    if not_ok or correct < total:
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -148,7 +148,7 @@ def moe_apply_local(p, cfg: ModelConfig, x, mesh):
     tokens, builds dispatch buffers ONLY for its local experts, runs them,
     scatters results back to token rows, and psums over "model".
     """
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     e = cfg.moe

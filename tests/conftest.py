@@ -4,18 +4,8 @@ import os
 # xla_force_host_platform_device_count (and only in its own process).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import sys
-
 import numpy as np
 import pytest
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    # The image has no hypothesis and nothing may be installed; alias the
-    # deterministic stub so property tests still run a seeded sweep.
-    import _hypothesis_stub
-    sys.modules["hypothesis"] = _hypothesis_stub
 
 
 @pytest.fixture(scope="session")

@@ -62,7 +62,7 @@ print("OK multi-device frontend == engine")
 
 for name, w in workers.items():
     for tile in w.tiles._tiles.values():
-        tile_devs = {d for d in tile.devices()}
+        tile_devs = {d for d in tile.lines.devices()}
         assert tile_devs == {w.device}, (name, tile_devs, w.device)
 print("OK tiles pinned per host device")
 
