@@ -1,0 +1,8 @@
+"""p50_ms (ms): median, by nearest rank, of every request of the window,
+timed at the client from the moment it was due. A request that failed or
+never came back counts as late as the run waited for it. Host clock."""
+import readings
+
+
+def read(run):
+    return readings.nearest_rank(readings.latencies_s(run), 0.5) * 1e3
