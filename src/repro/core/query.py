@@ -38,6 +38,7 @@ from . import dna, hashing
 from .arena import ArenaLayout, DeviceTileCache, common_tile_rows
 from .index import BitSlicedIndex, IndexParams
 from ..kernels import ops
+from ..obs.trace import span
 
 
 # --------------------------------------------------------------------------
@@ -172,27 +173,35 @@ def select_top_k(scores: np.ndarray, n_terms: int, k: int) -> "SearchResult":
     return SearchResult(order.astype(np.int32), top, n_terms, int(top[-1]))
 
 
-def run_paged(tiles, shard_args, fn, *args) -> list[np.ndarray]:
+def run_paged(tiles, shard_args, fn, *args, rec=None) -> np.ndarray:
     """Dispatch ``fn`` once per shard tile with double-buffered prefetch,
-    shared by the QueryEngine and the serving QueryServer.
+    shared by the QueryEngine and the serving QueryServer, and concatenate
+    the per-shard slot scores along the last axis.
 
     While shard i's scoring call is in flight (jax dispatch is async),
     shard i+1 stages host->device through ``tiles.prefetch`` — transfer
     overlaps compute. Results are forced to host only after every dispatch
     is issued. ``shard_args`` is [(shard, row_offset_dev, block_width_dev)]
-    and ``fn(tile, offs, widths, *args)`` the planned scorer."""
+    and ``fn(tile, offs, widths, *args)`` the planned scorer. ``rec`` (a
+    tracing BatchRecorder, or None) times each shard's ``tile_get`` (its
+    prefetch and get), each ``dispatch`` and the final ``readback``."""
     parts = []
-    for i, (s, offs, widths) in enumerate(shard_args):
-        tile = tiles.get(s)
-        out = fn(tile, offs, widths, *args)
+    with span(rec, "tile_get"):
+        tile = tiles.get(shard_args[0][0])
+    for i, (_, offs, widths) in enumerate(shard_args):
+        with span(rec, "dispatch"):
+            parts.append(fn(tile, offs, widths, *args))
         if i + 1 < len(shard_args):
-            tiles.prefetch(shard_args[i + 1][0])
-        parts.append(out)
-    return [np.asarray(p) for p in parts]
+            nxt = shard_args[i + 1][0]
+            with span(rec, "tile_get"):
+                tiles.prefetch(nxt)
+                tile = tiles.get(nxt)
+    with span(rec, "readback"):
+        return np.concatenate([np.asarray(p) for p in parts], axis=-1)
 
 
-def run_paged_compressed(tiles, shard_args, fn_raw, fn_comp, *args
-                         ) -> list[np.ndarray]:
+def run_paged_compressed(tiles, shard_args, fn_raw, fn_comp, *args,
+                         rec=None) -> np.ndarray:
     """``run_paged`` with per-shard codec dispatch: dict-coded shards stage
     their COMPRESSED (dict, refs) form to device and score through
     ``fn_comp(dict_rows, refs, offs, widths, *args)`` — the fused-decode
@@ -202,19 +211,25 @@ def run_paged_compressed(tiles, shard_args, fn_raw, fn_comp, *args
     storage = tiles.storage
     comp = [storage.shard_codec(s) in _codec.DICT_CODECS
             for (s, _, _) in shard_args]
+
+    def get(i):
+        s = shard_args[i][0]
+        return tiles.get_compressed(s) if comp[i] else (tiles.get(s),)
+
     parts = []
-    for i, (s, offs, widths) in enumerate(shard_args):
-        if comp[i]:
-            dict_rows, refs = tiles.get_compressed(s)
-            out = fn_comp(dict_rows, refs, offs, widths, *args)
-        else:
-            out = fn_raw(tiles.get(s), offs, widths, *args)
+    with span(rec, "tile_get"):
+        tile = get(0)
+    for i, (_, offs, widths) in enumerate(shard_args):
+        with span(rec, "dispatch"):
+            parts.append((fn_comp if comp[i] else fn_raw)(
+                *tile, offs, widths, *args))
         if i + 1 < len(shard_args):
-            nxt = shard_args[i + 1][0]
-            (tiles.prefetch_compressed if comp[i + 1]
-             else tiles.prefetch)(nxt)
-        parts.append(out)
-    return [np.asarray(p) for p in parts]
+            with span(rec, "tile_get"):
+                (tiles.prefetch_compressed if comp[i + 1]
+                 else tiles.prefetch)(shard_args[i + 1][0])
+                tile = get(i + 1)
+    with span(rec, "readback"):
+        return np.concatenate([np.asarray(p) for p in parts], axis=-1)
 
 
 # --------------------------------------------------------------------------
@@ -336,13 +351,14 @@ def make_comp_dedup_score_fn(word_block: int | None = None):
 
 def run_paged_dedup(tiles, shard_plans: list[ShardPlan], fn,
                     terms: np.ndarray, n_valid: np.ndarray,
-                    n_hashes: int = 1, fn_comp=None) -> np.ndarray:
+                    n_hashes: int = 1, fn_comp=None, rec=None) -> np.ndarray:
     """Dedup-scored batch across shard tiles (one tile = the whole arena
     for dense storage): per shard, plan the unique-row set against the
     shard's REBASED addressing, score through ``fn`` (from
     ``make_dedup_score_fn``), prefetch the next tile while the dispatch is
     in flight, and concatenate per-shard slot scores — the dedup analogue
-    of ``run_paged``.
+    of ``run_paged``, recording the same spans into ``rec`` and each
+    shard's planned rows going to the device as ``upload``.
 
     With ``fn_comp`` (from ``make_comp_dedup_score_fn``) dict-coded shards
     stage compressed and score through the fused-decode kernels; raw
@@ -355,19 +371,21 @@ def run_paged_dedup(tiles, shard_plans: list[ShardPlan], fn,
     for i, sp in enumerate(shard_plans):
         dp = plan_dedup_batch(terms, n_valid, sp.row_offset, sp.block_width,
                               n_hashes=n_hashes)
-        planned = (jnp.asarray(dp.uniq_rows), jnp.asarray(dp.indir),
-                   jnp.asarray(dp.mask))
-        if comp[i]:
-            dict_rows, refs = tiles.get_compressed(sp.shard)
-            out = fn_comp(dict_rows, refs, *planned)
-        else:
-            out = fn(tiles.get(sp.shard), *planned)
+        with span(rec, "upload"):
+            planned = (jnp.asarray(dp.uniq_rows), jnp.asarray(dp.indir),
+                       jnp.asarray(dp.mask))
+        with span(rec, "tile_get"):
+            tile = (tiles.get_compressed(sp.shard) if comp[i]
+                    else (tiles.get(sp.shard),))
+        with span(rec, "dispatch"):
+            parts.append((fn_comp if comp[i] else fn)(*tile, *planned))
         if i + 1 < len(shard_plans):
             nxt = shard_plans[i + 1].shard
-            (tiles.prefetch_compressed if comp[i + 1]
-             else tiles.prefetch)(nxt)
-        parts.append(out)
-    return np.concatenate([np.asarray(p) for p in parts], axis=1)
+            with span(rec, "tile_get"):
+                (tiles.prefetch_compressed if comp[i + 1]
+                 else tiles.prefetch)(nxt)
+    with span(rec, "readback"):
+        return np.concatenate([np.asarray(p) for p in parts], axis=1)
 
 
 # --------------------------------------------------------------------------
@@ -405,7 +423,9 @@ class PruneStats:
 
     ``bytes_read`` is the headline number: host arena bytes actually read
     (row gathers + promoted tile stagings) — the quantity the exhaustive
-    path pays ``sum(shard_nbytes)`` for."""
+    path pays ``sum(shard_nbytes)`` for. ``syncs`` counts the device->host
+    read-backs the executor waits on (block maxima, top-k bounds, final
+    scores)."""
     blocks_total: int = 0        # live (query, block) cells at entry
     blocks_pruned: int = 0       # cells dropped before the final chunk
     chunks: int = 0              # term chunks executed
@@ -413,6 +433,7 @@ class PruneStats:
     shard_visits_skipped: int = 0  # visits skipped (no live cell)
     tiles_promoted: int = 0      # shards escalated to full-tile staging
     kernel_dispatches: int = 0
+    syncs: int = 0               # device->host read-backs
     bytes_gathered: int = 0      # host bytes read by row gathers
     bytes_tile_staged: int = 0   # bytes of promoted full tiles
 
@@ -430,7 +451,8 @@ class PruneStats:
         """Accumulate another batch's counters (serving aggregates)."""
         for f in ("blocks_total", "blocks_pruned", "chunks", "shard_visits",
                   "shard_visits_skipped", "tiles_promoted",
-                  "kernel_dispatches", "bytes_gathered", "bytes_tile_staged"):
+                  "kernel_dispatches", "syncs", "bytes_gathered",
+                  "bytes_tile_staged"):
             setattr(self, f, getattr(self, f) + getattr(other, f))
 
 
@@ -479,7 +501,7 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
                      topk: np.ndarray, *, n_hashes: int = 1,
                      chunk_terms: int = 32, word_block: int | None = None,
                      promote_ratio: float = 0.5, order: np.ndarray | None = None,
-                     stats: PruneStats | None = None) -> np.ndarray:
+                     stats: PruneStats | None = None, rec=None) -> np.ndarray:
     """Branch-and-bound batch scoring across shard tiles.
 
     terms uint32 [Q, L, 2] (shared padding), n_valid int32 [Q];
@@ -494,7 +516,11 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
 
     ``order`` overrides the term execution order ([Q, L] permutation,
     valid-first); default is ``order_terms_rarest``. ``stats`` (a
-    PruneStats) is mutated with work/IO accounting."""
+    PruneStats) is mutated with work/IO accounting. ``rec`` (a tracing
+    BatchRecorder, or None) times ``prune_plan`` (hashing and ordering),
+    and per (chunk, shard) visit ``prune_gather`` (rows gathered and
+    staged), ``prune_dispatch`` (the kernel call) and ``prune_sync``
+    (each device->host read-back)."""
     terms = np.asarray(terms)
     n_valid = np.asarray(n_valid, dtype=np.int32)
     required = np.asarray(required, dtype=np.int64).copy()
@@ -512,12 +538,19 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
     if l_max == 0 or Q == 0:
         return np.zeros((Q, sum(nbs) * W * 32), dtype=np.int32)
 
-    if order is None:
-        order = order_terms_rarest(storage, shard_plans, terms, n_valid,
-                                   n_hashes=k)
-    h = hashing.hash_terms_np(terms, k)                   # [Q, L, k]
-    h_ord = np.take_along_axis(h, np.asarray(order, np.int64)[..., None],
-                               axis=1)
+    with span(rec, "prune_plan"):
+        if order is None:
+            order = order_terms_rarest(storage, shard_plans, terms, n_valid,
+                                       n_hashes=k)
+        h = hashing.hash_terms_np(terms, k)               # [Q, L, k]
+        h_ord = np.take_along_axis(
+            h, np.asarray(order, np.int64)[..., None], axis=1)
+
+    def sync(x) -> np.ndarray:
+        """One device->host read-back the executor waits on."""
+        stats.syncs += 1
+        with span(rec, "prune_sync"):
+            return np.asarray(x)
 
     alive = [np.ones((Q, nb), dtype=bool) for nb in nbs]
     acc = [None] * n_sh
@@ -553,113 +586,119 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
                 continue
             stats.shard_visits += 1
             visited.append(s)
-            rows = (h_chunk[..., None] % wids[s] + offs[s])  # [Q, ct, k, nb]
-            rows = np.transpose(rows, (0, 3, 1, 2)).astype(np.int64)
-            if acc[s] is None:
-                acc[s] = ops.chunk_acc_init(Q, nbs[s], W,
-                                            word_block=word_block)
-            hbm = storage.shard_hbm_nbytes(sp.shard)
-            if (not promoted[s] and not prefetch_issued[s]
-                    and gathered[s] >= 0.5 * promote_ratio * hbm):
-                # Double-buffer the promotion: once gathers cross half the
-                # promote threshold the full tile is prefetched (a
-                # non-blocking H2D dispatch), so it overlaps the remaining
-                # gather-fed chunks and is already resident when the
-                # threshold trips — promotion never stalls on a staging.
-                prefetch_issued[s] = True
-                if codecs[s] in _codec.DICT_CODECS:
-                    tiles.prefetch_compressed(sp.shard)
-                else:
-                    tiles.prefetch(sp.shard)
-            if not promoted[s] and gathered[s] >= promote_ratio * hbm:
-                promoted[s] = True
-                if codecs[s] in _codec.DICT_CODECS:
-                    resident[s] = tiles.get_compressed(sp.shard)
-                else:
-                    resident[s] = tiles.get(sp.shard)
-                stats.tiles_promoted += 1
-                stats.bytes_tile_staged += hbm
-            mask = jnp.asarray(live.astype(np.int32))
-            if promoted[s] and k == 1:
-                idx = jnp.asarray(rows[..., 0].astype(np.int32))
-                if codecs[s] in _codec.DICT_CODECS:
-                    d, r = resident[s]
-                    acc[s], bmax = ops.bitslice_chunk_score_multi_comp(
-                        d, r, idx, mask, acc[s], word_block=word_block)
-                else:
-                    acc[s], bmax = ops.bitslice_chunk_score_multi(
-                        resident[s], idx, mask, acc[s], word_block=word_block)
-            elif promoted[s]:
-                # k>1 promoted path: the chunk's unique row SETS are still
-                # planned host-side (np.unique over live cells), but the
-                # rows themselves are gathered and ANDed on DEVICE out of
-                # the resident tile — no host arena reads after promotion.
-                cells = rows[live]                        # [N, k]
-                uniq, inv = np.unique(cells, axis=0, return_inverse=True)
-                u_idx = np.zeros((_pad_unique(uniq.shape[0]), k),
-                                 dtype=np.int32)
-                u_idx[: uniq.shape[0]] = uniq
-                if codecs[s] in _codec.DICT_CODECS:
-                    d, r = resident[s]
-                    mat_dev = ops.gather_and_rows_comp(
-                        d, r, jnp.asarray(u_idx))
-                else:
-                    mat_dev = ops.gather_and_rows(
-                        resident[s], jnp.asarray(u_idx))
-                indir = np.zeros((Q, nbs[s], ct), dtype=np.int32)
-                indir[live] = np.asarray(inv).reshape(-1).astype(np.int32)
-                acc[s], bmax = ops.bitslice_chunk_score_dedup(
-                    mat_dev, jnp.asarray(indir), mask, acc[s],
-                    word_block=word_block)
-            else:
-                cells = rows[live]                        # [N, k]
-                if k == 1:
-                    uniq, inv = np.unique(cells[:, 0], return_inverse=True)
-                else:
+            with span(rec, "prune_gather"):
+                # the visit's chunk kernel and its operands but acc
+                rows = (h_chunk[..., None] % wids[s] + offs[s])  # [Q,ct,k,nb]
+                rows = np.transpose(rows, (0, 3, 1, 2)).astype(np.int64)
+                if acc[s] is None:
+                    acc[s] = ops.chunk_acc_init(Q, nbs[s], W,
+                                                word_block=word_block)
+                hbm = storage.shard_hbm_nbytes(sp.shard)
+                if (not promoted[s] and not prefetch_issued[s]
+                        and gathered[s] >= 0.5 * promote_ratio * hbm):
+                    # Double-buffer the promotion: once gathers cross half
+                    # the promote threshold the full tile is prefetched (a
+                    # non-blocking H2D dispatch), so it overlaps the
+                    # remaining gather-fed chunks and is already resident
+                    # when the threshold trips — promotion never stalls on
+                    # a staging.
+                    prefetch_issued[s] = True
+                    if codecs[s] in _codec.DICT_CODECS:
+                        tiles.prefetch_compressed(sp.shard)
+                    else:
+                        tiles.prefetch(sp.shard)
+                if not promoted[s] and gathered[s] >= promote_ratio * hbm:
+                    promoted[s] = True
+                    if codecs[s] in _codec.DICT_CODECS:
+                        resident[s] = tiles.get_compressed(sp.shard)
+                    else:
+                        resident[s] = tiles.get(sp.shard)
+                    stats.tiles_promoted += 1
+                    stats.bytes_tile_staged += hbm
+                mask = jnp.asarray(live.astype(np.int32))
+                if promoted[s] and k == 1:
+                    idx = jnp.asarray(rows[..., 0].astype(np.int32))
+                    if codecs[s] in _codec.DICT_CODECS:
+                        kernel = ops.bitslice_chunk_score_multi_comp
+                        args = (*resident[s], idx, mask)
+                    else:
+                        kernel = ops.bitslice_chunk_score_multi
+                        args = (resident[s], idx, mask)
+                elif promoted[s]:
+                    # k>1 promoted path: the chunk's unique row SETS are
+                    # still planned host-side (np.unique over live cells),
+                    # but the rows themselves are gathered and ANDed on
+                    # DEVICE out of the resident tile — no host arena
+                    # reads after promotion.
+                    cells = rows[live]                    # [N, k]
                     uniq, inv = np.unique(cells, axis=0, return_inverse=True)
-                if codecs[s] in _codec.DICT_CODECS:
-                    d_host, r_host = storage.shard_dict_host(sp.shard)
-                    refs = np.asarray(r_host)[uniq]       # [U] or [U, k]
-                    mat = np.asarray(d_host[refs.reshape(-1)],
-                                     dtype=np.uint32)
-                    nread = int(np.unique(refs).size)
+                    u_idx = np.zeros((_pad_unique(uniq.shape[0]), k),
+                                     dtype=np.int32)
+                    u_idx[: uniq.shape[0]] = uniq
+                    if codecs[s] in _codec.DICT_CODECS:
+                        d, r = resident[s]
+                        mat_dev = ops.gather_and_rows_comp(
+                            d, r, jnp.asarray(u_idx))
+                    else:
+                        mat_dev = ops.gather_and_rows(
+                            resident[s], jnp.asarray(u_idx))
+                    indir = np.zeros((Q, nbs[s], ct), dtype=np.int32)
+                    indir[live] = np.asarray(inv).reshape(-1).astype(np.int32)
+                    kernel = ops.bitslice_chunk_score_dedup
+                    args = (mat_dev, jnp.asarray(indir), mask)
                 else:
-                    if (codecs[s] != _codec.CODEC_RAW
-                            and not decode_counted[s]):
-                        # non-dict compressed shards decode whole on touch
-                        decode_counted[s] = True
-                        stats.bytes_gathered += storage.shard_nbytes(sp.shard)
-                    host = storage.shard_host(sp.shard)
-                    mat = np.asarray(host[uniq.reshape(-1)],
+                    cells = rows[live]                    # [N, k]
+                    if k == 1:
+                        uniq, inv = np.unique(cells[:, 0], return_inverse=True)
+                    else:
+                        uniq, inv = np.unique(cells, axis=0,
+                                              return_inverse=True)
+                    if codecs[s] in _codec.DICT_CODECS:
+                        d_host, r_host = storage.shard_dict_host(sp.shard)
+                        refs = np.asarray(r_host)[uniq]   # [U] or [U, k]
+                        mat = np.asarray(d_host[refs.reshape(-1)],
+                                         dtype=np.uint32)
+                        nread = int(np.unique(refs).size)
+                    else:
+                        if (codecs[s] != _codec.CODEC_RAW
+                                and not decode_counted[s]):
+                            # non-dict compressed shards decode whole on
+                            # touch
+                            decode_counted[s] = True
+                            stats.bytes_gathered += storage.shard_nbytes(
+                                sp.shard)
+                        host = storage.shard_host(sp.shard)
+                        mat = np.asarray(host[uniq.reshape(-1)],
+                                         dtype=np.uint32)
+                        nread = int(uniq.reshape(-1).size)
+                    if codecs[s] == _codec.CODEC_RAW:
+                        stats.bytes_gathered += nread * W * 4
+                    elif codecs[s] in _codec.DICT_CODECS:
+                        stats.bytes_gathered += nread * W * 4
+                    gathered[s] += mat.shape[0] * W * 4
+                    if k > 1:
+                        mat = mat.reshape(-1, k, W)
+                        anded = mat[:, 0]
+                        for i in range(1, k):
+                            anded = anded & mat[:, i]
+                        mat = anded
+                    u_pad = np.zeros((_pad_unique(mat.shape[0]), W),
                                      dtype=np.uint32)
-                    nread = int(uniq.reshape(-1).size)
-                if codecs[s] == _codec.CODEC_RAW:
-                    stats.bytes_gathered += nread * W * 4
-                elif codecs[s] in _codec.DICT_CODECS:
-                    stats.bytes_gathered += nread * W * 4
-                gathered[s] += mat.shape[0] * W * 4
-                if k > 1:
-                    mat = mat.reshape(-1, k, W)
-                    anded = mat[:, 0]
-                    for i in range(1, k):
-                        anded = anded & mat[:, i]
-                    mat = anded
-                u_pad = np.zeros((_pad_unique(mat.shape[0]), W),
-                                 dtype=np.uint32)
-                u_pad[: mat.shape[0]] = mat
-                indir = np.zeros((Q, nbs[s], ct), dtype=np.int32)
-                indir[live] = np.asarray(inv).reshape(-1).astype(np.int32)
-                acc[s], bmax = ops.bitslice_chunk_score_dedup(
-                    jnp.asarray(u_pad), jnp.asarray(indir), mask, acc[s],
-                    word_block=word_block)
+                    u_pad[: mat.shape[0]] = mat
+                    indir = np.zeros((Q, nbs[s], ct), dtype=np.int32)
+                    indir[live] = np.asarray(inv).reshape(-1).astype(np.int32)
+                    kernel = ops.bitslice_chunk_score_dedup
+                    args = (jnp.asarray(u_pad), jnp.asarray(indir), mask)
+            with span(rec, "prune_dispatch"):
+                acc[s], bmax = kernel(*args, acc[s], word_block=word_block)
             stats.kernel_dispatches += 1
-            block_max[s] = np.asarray(bmax).astype(np.int64)
+            block_max[s] = sync(bmax).astype(np.int64)
 
         if c == n_chunks - 1:
             break
         if kmax > 0:
             for s in visited:
-                tk_lower[s] = np.asarray(ops.chunk_topk_lower(acc[s], kmax))
+                tk_lower[s] = sync(ops.chunk_topk_lower(acc[s], kmax))
             have = [t for t in tk_lower if t is not None]
             if have:
                 merged = -np.sort(-np.concatenate(have, axis=1), axis=1)
@@ -684,7 +723,7 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
         if acc[s] is None:
             parts.append(np.zeros((Q, nbs[s] * W * 32), dtype=np.int32))
         else:
-            parts.append(np.asarray(ops.chunk_acc_scores(acc[s], W)))
+            parts.append(sync(ops.chunk_acc_scores(acc[s], W)))
     return np.concatenate(parts, axis=1)
 
 
@@ -1188,11 +1227,10 @@ class QueryEngine:
                 self.tiles.get(0), self.index.row_offset,
                 self.index.block_width, padded, L))
         if self.compressed:
-            return np.concatenate(run_paged_compressed(
+            return run_paged_compressed(
                 self.tiles, self._shard_args, self._score, self._score_comp,
-                padded, L))
-        return np.concatenate(
-            run_paged(self.tiles, self._shard_args, self._score, padded, L))
+                padded, L)
+        return run_paged(self.tiles, self._shard_args, self._score, padded, L)
 
     def _score_slots_batch(self, terms: jnp.ndarray, n_valid: jnp.ndarray
                            ) -> np.ndarray:
@@ -1206,12 +1244,11 @@ class QueryEngine:
                 self.tiles.get(0), self.index.row_offset,
                 self.index.block_width, terms, n_valid))
         if self.compressed:
-            return np.concatenate(run_paged_compressed(
+            return run_paged_compressed(
                 self.tiles, self._shard_args, self._score_batch,
-                self._score_batch_comp, terms, n_valid), axis=1)
-        return np.concatenate(
-            run_paged(self.tiles, self._shard_args, self._score_batch,
-                      terms, n_valid), axis=1)
+                self._score_batch_comp, terms, n_valid)
+        return run_paged(self.tiles, self._shard_args, self._score_batch,
+                         terms, n_valid)
 
     def score_terms(self, terms: np.ndarray) -> np.ndarray:
         """Distinct packed terms [L, 2] -> int32 scores [n_docs] (original

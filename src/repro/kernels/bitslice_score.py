@@ -79,6 +79,7 @@ def unpack_score(
         out_specs=pl.BlockSpec((word_block, 32), lambda iw, il: (iw, 0)),
         out_shape=jax.ShapeDtypeStruct((W, 32), jnp.int32),
         interpret=interpret,
+        name="bitslice_unpack",
     )(rows)
 
 
@@ -144,6 +145,7 @@ def vertical_score(
         out_shape=jax.ShapeDtypeStruct((W, 32), jnp.int32),
         scratch_shapes=[pltpu.VMEM((n_planes, word_block), jnp.uint32)],
         interpret=interpret,
+        name="bitslice_vertical",
     )(rows)
 
 
@@ -327,6 +329,7 @@ def lookup_score_multi(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Q, nb, ws, 32), jnp.int32),
         interpret=interpret,
+        name="bitslice_lookup",
     )(*operands)
 
 
@@ -392,6 +395,7 @@ def gather_rows(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((U, lines * LANES), jnp.uint32),
         interpret=interpret,
+        name="bitslice_gather_rows",
     )(uniq_idx, arena_lines)
 
 
@@ -460,4 +464,5 @@ def dedup_score(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Q, nb, ws, 32), jnp.int32),
         interpret=interpret,
+        name="bitslice_dedup_score",
     )(*operands)

@@ -12,18 +12,18 @@ Three cooperating pieces, each usable alone:
   gather, delivery), and the finished trace lands in a ring buffer —
   plus the slow-query JSONL log when it blows a latency budget.
 * ``profile`` — ``KernelProfiler`` wraps the score-kernel dispatch,
-  recording per-(method, bucket, word_block) wall time and bytes-moved
-  estimates, and optionally feeds the measurements back into the
-  autotuner's cost cache as live "observed" entries.
+  recording per-(method, bucket, word_block) wall time, and optionally
+  feeds the measurements back into the autotuner's cost cache as live
+  "observed" entries.
 """
 from .events import EventLog
 from .profile import KernelProfiler
 from .registry import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import Span, Trace, Tracer
+from .trace import BatchRecorder, Span, Trace, Tracer, span
 from .export import render_prometheus
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Span", "Trace", "Tracer",
+    "BatchRecorder", "Span", "Trace", "Tracer", "span",
     "EventLog", "KernelProfiler", "render_prometheus",
 ]

@@ -1,10 +1,9 @@
-"""Kernel profiling: per-(method, bucket, word_block) wall time and
-bytes-moved accounting for every score dispatch.
+"""Kernel profiling: per-(method, bucket, word_block) wall time for
+every score dispatch.
 
 The serving layers already know everything worth recording at the
 moment a kernel returns — the method the planner chose, the bucket and
-batch geometry, the word_block actually dispatched, and (for the
-dedup path) how many arena rows the gather streamed. ``KernelProfiler.
+batch geometry, and the word_block actually dispatched. ``KernelProfiler.
 record`` is the single funnel: it feeds a labeled histogram + counter
 in the metrics registry (Prometheus-visible), keeps a bounded ring of
 raw records for tests/reports, and forwards each measurement to
@@ -16,14 +15,6 @@ from __future__ import annotations
 import threading
 from collections import deque
 from typing import Optional
-
-
-def gather_bytes(n_rows: int, doc_words: int, itemsize: int = 4) -> int:
-    """Bytes-moved estimate for an arena gather: rows streamed from the
-    bit-sliced arena times the row stride. The dedup plan's
-    ``n_unique`` (padded) rows for the dedup path, Q*nb*L for the fused
-    kernel — per-slice addressing reads whole rows either way."""
-    return int(n_rows) * int(doc_words) * int(itemsize)
 
 
 class KernelProfiler:
@@ -38,7 +29,6 @@ class KernelProfiler:
         self._ring: "deque[dict]" = deque(maxlen=ring)
         self._count = 0
         self._hist = None
-        self._bytes = None
         if registry is not None:
             self.bind_registry(registry)
 
@@ -47,25 +37,19 @@ class KernelProfiler:
             "kernel_score_seconds",
             "score-kernel wall time per dispatch",
             labels=("method", "bucket", "word_block"))
-        self._bytes = registry.counter(
-            "kernel_bytes_moved_total",
-            "estimated arena bytes gathered by score dispatches",
-            labels=("method", "bucket"))
 
     def record(self, *, method: str, bucket: int, batch: int,
                seconds: float, word_block: int = 0,
                term_block: int = 0, grid_order: str = "wq",
-               bytes_moved: int = 0, shard: Optional[int] = None) -> None:
+               shard: Optional[int] = None) -> None:
         """One finished kernel dispatch."""
         if not self.enabled:
             return
         if self._hist is not None:
             self._hist.labels(method, bucket, word_block).observe(seconds)
-        if self._bytes is not None and bytes_moved:
-            self._bytes.labels(method, bucket).inc(bytes_moved)
         rec = {"method": method, "bucket": int(bucket),
                "batch": int(batch), "word_block": int(word_block),
-               "seconds": float(seconds), "bytes_moved": int(bytes_moved)}
+               "seconds": float(seconds)}
         if shard is not None:
             rec["shard"] = int(shard)
         with self._lock:

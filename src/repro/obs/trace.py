@@ -10,22 +10,37 @@ kernel) are legitimately shared by every request in the micro-batch.
 The tree structure a UI would want is recoverable from the intervals;
 ``benchmarks/trace_report.py`` renders exactly that.
 
+A scored micro-batch's stages are timed once, by the ``BatchRecorder``
+the tracer hands ``score_batch``, and copied into every member request's
+trace when the batch is answered. Its spans carry the batch id and the
+name of the enclosing stage (``parent``), so a stage's self time is its
+duration less its children's. Each is also a
+``jax.profiler.TraceAnnotation`` of the same name over the same interval,
+so inside a profiler capture the program's stages sit on the host threads
+of the trace, on the profiler's clock, beside the device ops. The
+recorder is None when tracing is off and call sites take the
+``span(rec, name)`` no-op then.
+
 ``Tracer`` owns trace lifecycle: minting ids, the bounded ring of
 finished traces (for the STATS surface / tests), and the slow-query
 sink — a finished trace whose end-to-end latency exceeds ``slow_ms``
 is emitted to the JSONL ``EventLog`` with its full span tree.
 
-Everything is cheap when disabled: ``tracer.begin`` returns None and
-every call site guards with ``if trace is not None`` (span recording
-itself is two clock reads and an append under a small lock).
+Everything is cheap when disabled: ``tracer.begin`` and
+``tracer.batch`` return None and every call site guards with ``if trace
+is not None`` or goes through ``span`` (span recording itself is two
+clock reads and an append under a small lock).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
 from collections import deque
 from typing import Callable, Optional
+
+from jax import profiler
 
 
 class Span:
@@ -78,6 +93,12 @@ class Trace:
             self._spans.append(s)
         return s
 
+    def extend(self, spans: list[Span]) -> None:
+        """Append spans recorded elsewhere (a batch's, shared by every
+        request of the batch)."""
+        with self._lock:
+            self._spans.extend(spans)
+
     @property
     def done(self) -> bool:
         return self.ended_s is not None
@@ -115,6 +136,58 @@ class Trace:
         }
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(rec: Optional["BatchRecorder"], name: str,
+         tags: Optional[dict] = None):
+    """``rec.span(name, **tags)``, or a shared no-op context when tracing
+    is off (``rec`` None): no clock read, no annotation, no allocation."""
+    return _NO_SPAN if rec is None else rec.span(name, **(tags or {}))
+
+
+class BatchRecorder:
+    """The spans of one scored micro-batch, recorded by the one thread
+    that scores it."""
+
+    def __init__(self, batch: int, clock: Callable[[], float]):
+        self.batch = batch
+        self.clock = clock
+        self.spans: list[Span] = []
+        self._open: list[str] = []           # enclosing span names
+
+    def _tags(self, tags: dict) -> dict:
+        tags["batch"] = self.batch
+        if self._open:
+            tags["parent"] = self._open[-1]
+        return tags
+
+    @contextlib.contextmanager
+    def span(self, name: str, **tags):
+        """Time the block as stage ``name``; yields the span's tags, which
+        the block may add to. The profiler annotation opens before the
+        first clock read and closes after the last, so both record the
+        same interval."""
+        tags = self._tags(tags)
+        self._open.append(name)
+        with profiler.TraceAnnotation(name):
+            t0 = self.clock()
+            yield tags
+            t1 = self.clock()
+        self._open.pop()
+        self.spans.append(Span(name, t0, t1, tags))
+
+    def add(self, name: str, start_s: float, end_s: float, **tags) -> None:
+        """A stage timed elsewhere (a tile staging the cache reports, a
+        shard dispatch's latency), under the open span; it has no
+        profiler annotation."""
+        self.spans.append(Span(name, start_s, end_s, self._tags(tags)))
+
+    def finished(self) -> list[Span]:
+        """The batch's spans in start order, to copy into each request."""
+        return sorted(self.spans, key=lambda s: s.start_s)
+
+
 class Tracer:
     """Trace factory + finished-trace ring + slow-query sink.
 
@@ -139,6 +212,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._ring: "deque[Trace]" = deque(maxlen=ring)
         self._ids = itertools.count(1)
+        self._batch_ids = itertools.count(1)
         self._finished = 0
         self._slow = 0
 
@@ -147,14 +221,30 @@ class Tracer:
 
     def begin(self, request_id: int = 0, *,
               trace_id: Optional[int] = None,
-              started_s: Optional[float] = None) -> Optional[Trace]:
+              started_s: Optional[float] = None,
+              spans: tuple = ()) -> Optional[Trace]:
         """New trace, or None when tracing is off. A nonzero wire
-        trace id (client-minted) is honored verbatim."""
+        trace id (client-minted) is honored verbatim. ``spans`` are
+        (name, start, end) stages the request passed before its trace
+        existed (the wire's ``decode``, the loop's ``lock_wait``): they
+        are recorded into it, and the trace starts at the first."""
         if not self.enabled:
             return None
         tid = trace_id if trace_id else self.mint_id()
         t0 = self.clock() if started_s is None else started_s
-        return Trace(tid, request_id, started_s=t0)
+        if spans:
+            t0 = min(t0, spans[0][1])
+        trace = Trace(tid, request_id, started_s=t0)
+        for name, s, e in spans:
+            trace.add(name, s, e)
+        return trace
+
+    def batch(self, requests) -> Optional[BatchRecorder]:
+        """A recorder for one micro-batch, or None when none of its
+        requests is traced."""
+        if not any(r.trace is not None for r in requests):
+            return None
+        return BatchRecorder(next(self._batch_ids), self.clock)
 
     def finish(self, trace: Optional[Trace]) -> None:
         """Seal the trace, ring-buffer it, and emit to the slow-query

@@ -196,11 +196,12 @@ class Frontend(ServingBackend):
                threshold: Optional[float] = None,
                top_k: Optional[int] = None,
                deadline: Optional[float] = None,
-               trace_id: int = 0) -> int:
+               trace_id: int = 0, pre_spans: tuple = ()) -> int:
         """Accept one query; ``top_k`` switches the request from coverage-
         threshold selection to exact global top-k. A nonzero ``trace_id``
         (e.g. minted by a remote client and carried over the wire) is
-        honored; otherwise the tracer mints one."""
+        honored; otherwise the tracer mints one. ``pre_spans`` are the
+        stages the request passed before it got here (``Tracer.begin``)."""
         if (pattern is None) == (terms is None):
             raise ValueError("pass exactly one of pattern / terms")
         if terms is None:
@@ -211,7 +212,7 @@ class Frontend(ServingBackend):
         rid = self._next_id
         self._next_id += 1
         trace = self.tracer.begin(rid, trace_id=trace_id or None,
-                                  started_s=now)
+                                  started_s=now, spans=pre_spans)
         if terms.shape[0] == 0:
             empty = SearchResult(np.zeros(0, np.int32),
                                  np.zeros(0, np.int32), 0, 0)
@@ -381,7 +382,7 @@ class Frontend(ServingBackend):
         canc0, skip0 = ex.hedges_cancelled, ex.skipped_dead
         tiles0 = self._tile_counters()
         prune0 = self._prune_counters()
-        traced = any(r.trace is not None for r in batch.requests)
+        rec = self.tracer.batch(batch.requests)
         method = ""
         t_sc0 = self.clock()
         try:
@@ -438,24 +439,24 @@ class Frontend(ServingBackend):
                 bytes_saved=max(0, (pr[4] - prune0[4])
                                 - (pr[3] - prune0[3])))
 
-        # Batch-level shard_dispatch marks, replayed into every member
+        # Batch-level shard_dispatch spans, copied into every member
         # request's trace: one span per shard naming the serving node and
         # its role — "primary" (the placement's preferred replica),
         # "backup" (a hedged backup request won the race), or "failover"
         # (the primary was found dead at dispatch time). The executor
         # appends exactly one completion per dispatch in shard order, so
         # the tail of ex.completions lines up with ``results``.
-        marks: list[tuple[str, float, float, dict]] = []
-        if traced:
+        spans = ()
+        if rec is not None:
             comps = list(ex.completions)[-len(results):]
             for g, (node, lat, _res) in enumerate(results):
                 hedged = bool(comps[g][3]) if g < len(comps) else False
                 replicas = self.placement.replicas(g)
                 role = ("primary" if replicas and node == replicas[0]
                         else ("backup" if hedged else "failover"))
-                marks.append(("shard_dispatch", t_sc0, t_sc0 + lat,
-                              {"shard": g, "node": node, "role": role,
-                               "hedged": int(hedged)}))
+                rec.add("shard_dispatch", t_sc0, t_sc0 + lat, shard=g,
+                        node=node, role=role, hedged=int(hedged))
+            spans = rec.finished()
 
         for i, r in enumerate(batch.requests):
             ts0 = self.clock()
@@ -470,8 +471,7 @@ class Frontend(ServingBackend):
                 r.trace.add("queue_wait", r.submitted_at, t0,
                             {"flush": batch.reason or "direct",
                              "batch_size": Q})
-                for name, s, e, tags in marks:
-                    r.trace.add(name, s, e, tags)
+                r.trace.extend(spans)
                 r.trace.add("gather", ts0, self.clock())
             self._responses[r.request_id] = self.finalize_trace(
                 r.trace, resp)

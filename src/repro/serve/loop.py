@@ -65,6 +65,7 @@ class ServingLoop:
         if workers < 1:
             raise ValueError("workers must be >= 1")
         self.backend = backend
+        self._tracer = getattr(backend, "tracer", None)
         self.poll_interval_s = poll_interval_s
         self.n_workers = workers
         # One reentrant lock serializes ALL backend access (submission,
@@ -104,9 +105,8 @@ class ServingLoop:
         # breakdown at response creation but leave the trace open so the
         # callback-delivery time lands in it as a final "deliver" span
         # (sealed in _deliver, after the callback returns).
-        tracer = getattr(self.backend, "tracer", None)
-        if tracer is not None:
-            tracer.defer_finish = True
+        if self._tracer is not None:
+            self._tracer.defer_finish = True
         d = threading.Thread(target=self._dispatch, name="serve-dispatch",
                              daemon=True)
         self._threads = [d] + [
@@ -133,27 +133,35 @@ class ServingLoop:
         for t in self._threads:
             t.join(timeout=timeout_s)
         self._threads = []
-        tracer = getattr(self.backend, "tracer", None)
-        if tracer is not None:
-            tracer.defer_finish = False
+        if self._tracer is not None:
+            self._tracer.defer_finish = False
 
     # -- submission ----------------------------------------------------------
     def submit(self, pattern=None, *, terms: Optional[np.ndarray] = None,
                threshold: Optional[float] = None,
                top_k: Optional[int] = None,
                deadline: Optional[float] = None,
-               trace_id: int = 0,
+               trace_id: int = 0, pre_spans: tuple = (),
                on_done: Callable[[QueryResponse], None]) -> int:
         """Thread-safe submit; ``on_done(response)`` fires exactly once —
         synchronously for fast paths (cache hit, point query, REJECTED),
-        from a loop thread otherwise. Raises LoopClosed after stop()."""
+        from a loop thread otherwise. Raises LoopClosed after stop().
+        Traced, the wait for the loop's lock is the request's
+        ``lock_wait`` span, after any ``pre_spans`` (name, start, end) the
+        caller timed before it (the wire's ``decode``)."""
+        traced = self._tracer is not None and self._tracer.enabled
+        if traced:
+            asked = self.clock()
         with self._lock:
+            if traced:
+                pre_spans = (*pre_spans, ("lock_wait", asked, self.clock()))
             if not self._accepting:
                 raise LoopClosed("serving loop is shut down")
             rid = self.backend.submit(pattern, terms=terms,
                                       threshold=threshold, top_k=top_k,
                                       deadline=deadline,
-                                      trace_id=trace_id)
+                                      trace_id=trace_id,
+                                      pre_spans=pre_spans)
             resp = self.backend.take_response(rid)
             if resp is None:
                 # END-TO-END backpressure: the batcher's cap only counts
@@ -203,7 +211,7 @@ class ServingLoop:
         return out
 
     def _deliver(self, ready: list[tuple[Callable, QueryResponse]]) -> None:
-        tracer = getattr(self.backend, "tracer", None)
+        tracer = self._tracer
         for cb, resp in ready:
             t0 = self.clock()
             try:

@@ -96,6 +96,7 @@ class MetricsSnapshot:
     prune_rate: float = 0.0       # killed / considered
     tiles_skipped: int = 0        # shard-tile visits never issued
     pruned_bytes_saved: int = 0   # arena bytes NOT read thanks to pruning
+    prune_syncs: int = 0          # device->host read-backs waited on
     # offline bulk lane (0 when no bulk job ever ran)
     bulk_jobs: int = 0            # jobs finished (any terminal status)
     bulk_queries: int = 0         # queries scored through the bulk lane
@@ -150,7 +151,8 @@ class MetricsSnapshot:
             s += (f" prune[blocks={self.pruned_blocks} "
                   f"rate={self.prune_rate:.2f} "
                   f"tiles_skipped={self.tiles_skipped} "
-                  f"bytes_saved={self.pruned_bytes_saved}B]")
+                  f"bytes_saved={self.pruned_bytes_saved}B "
+                  f"syncs={self.prune_syncs}]")
         if self.bulk_jobs or self.bulk_queries:
             s += (f" bulk[jobs={self.bulk_jobs} "
                   f"queries={self.bulk_queries} "
@@ -277,6 +279,9 @@ class ServingMetrics:
         self._prune_bytes_saved = r.counter(
             "serve_pruned_bytes_saved_total",
             "arena bytes not read thanks to pruning")
+        self._prune_syncs = r.counter(
+            "serve_prune_syncs_total",
+            "device-to-host read-backs the pruned executor waited on")
         # offline bulk lane: shard-major sweeps that run when no
         # interactive batch is due — per-job outcomes, shard/query
         # throughput, preemption yields, and the staged-bytes headline
@@ -374,10 +379,12 @@ class ServingMetrics:
         self._decode.observe(seconds)
 
     def record_prune(self, *, blocks_total: int, blocks_pruned: int,
-                     tiles_skipped: int, bytes_saved: int) -> None:
+                     tiles_skipped: int, bytes_saved: int,
+                     syncs: int = 0) -> None:
         """One pruned dispatch's accounting (a core.query.PruneStats
         delta): cells considered/killed by the bound, shard-tile visits
-        never issued, and arena bytes never read."""
+        never issued, arena bytes never read, and the device->host
+        read-backs it waited on."""
         if blocks_total:
             self._prune_considered.inc(blocks_total)
         if blocks_pruned:
@@ -386,6 +393,8 @@ class ServingMetrics:
             self._tiles_skipped.inc(tiles_skipped)
         if bytes_saved > 0:
             self._prune_bytes_saved.inc(bytes_saved)
+        if syncs:
+            self._prune_syncs.inc(syncs)
 
     def record_bulk_shard(self, *, staged_bytes: int,
                           seconds: float) -> None:
@@ -529,6 +538,10 @@ class ServingMetrics:
     @property
     def pruned_bytes_saved(self) -> int:
         return self._prune_bytes_saved.value
+
+    @property
+    def prune_syncs(self) -> int:
+        return self._prune_syncs.value
 
     @property
     def bulk_jobs(self) -> int:
@@ -681,6 +694,7 @@ class ServingMetrics:
                         if self.prune_considered else 0.0),
             tiles_skipped=self.tiles_skipped,
             pruned_bytes_saved=self.pruned_bytes_saved,
+            prune_syncs=self.prune_syncs,
             bulk_jobs=self.bulk_jobs,
             bulk_queries=self.bulk_queries,
             bulk_shards_swept=self.bulk_shards_swept,

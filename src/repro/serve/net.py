@@ -505,15 +505,20 @@ class _Session:
     drained by a dedicated writer thread. Loop threads enqueue replies
     and NEVER touch the socket — a client that stops reading fills its
     own outbox and gets kicked, instead of wedging a scoring worker in a
-    blocking sendall and stalling every other client."""
+    blocking sendall and stalling every other client.
+
+    A reply sent with its request's trace gets two spans there, on
+    ``clock``: ``outbox_wait`` (enqueued until the writer takes it) and
+    ``write`` (the ``write_frame``), possibly after the trace finished."""
 
     def __init__(self, sock: socket.socket,
-                 on_drop: Optional[Callable[[int], None]] = None):
+                 on_drop: Optional[Callable[[int], None]] = None,
+                 clock: Callable[[], float] = time.monotonic):
         self.sock = sock
-        self.outbox: "queue.Queue[Optional[bytes]]" = queue.Queue(
-            maxsize=OUTBOX_FRAMES)
+        self.outbox: "queue.Queue" = queue.Queue(maxsize=OUTBOX_FRAMES)
         self.dropped_replies = 0
         self._on_drop = on_drop
+        self._clock = clock
         self.writer = threading.Thread(target=self._write_loop,
                                        name="serve-write", daemon=True)
         self.writer.start()
@@ -528,9 +533,10 @@ class _Session:
             except Exception:
                 pass
 
-    def send(self, payload: bytes) -> None:
+    def send(self, payload: bytes, trace=None) -> None:
+        item = payload if trace is None else (payload, trace, self._clock())
         try:
-            self.outbox.put_nowait(payload)
+            self.outbox.put_nowait(item)
         except queue.Full:
             self._drop()
             self.kick()                       # slow reader: drop the session
@@ -541,6 +547,11 @@ class _Session:
             p = self.outbox.get()
             if p is None:
                 return
+            trace = None
+            if type(p) is tuple:              # a traced reply
+                p, trace, queued = p
+                taken = self._clock()
+                trace.add("outbox_wait", queued, taken)
             if dead:
                 self._drop()                  # drain, counting every loss
                 continue
@@ -549,6 +560,9 @@ class _Session:
             except OSError:
                 dead = True                   # client went away
                 self._drop()
+                continue
+            if trace is not None:
+                trace.add("write", taken, self._clock())
 
     def kick(self) -> None:
         """Force both directions down (unblocks reader AND writer)."""
@@ -565,22 +579,25 @@ class _Session:
         old code put() the sentinel with a timeout, so a full outbox at
         close silently orphaned every queued reply. A peer that stalls
         past the deadline is kicked and the writer's counting drain
-        accounts each undelivered frame in ``dropped_replies``."""
+        accounts each undelivered frame in ``dropped_replies``: the kick
+        comes first, so the writer is out of ``sendall`` before the
+        sentinel reaches it and the join can succeed."""
         deadline = time.monotonic() + timeout_s
         while not self.outbox.empty() and time.monotonic() < deadline:
             time.sleep(0.005)
-        try:
-            self.outbox.put(
-                None, timeout=max(0.01, deadline - time.monotonic()))
-        except queue.Full:
-            # writer wedged on a stalled peer: sever the socket so the
-            # write loop falls into its counting drain, then sentinel
+        if not self.outbox.empty():
+            # the peer stalled past the deadline: sever the socket so the
+            # write loop falls into its counting drain
             self.kick()
-            try:
-                self.outbox.put(None, timeout=timeout_s)
-            except queue.Full:
-                pass
-        self.writer.join(timeout=timeout_s)
+        try:
+            self.outbox.put(None, timeout=timeout_s)
+        except queue.Full:
+            pass
+        self.writer.join(timeout=max(0.01, deadline - time.monotonic()))
+        if self.writer.is_alive():
+            # the outbox emptied, but the last frame is stuck in sendall
+            self.kick()
+            self.writer.join(timeout=timeout_s)
         self.kick()
         self.sock.close()
 
@@ -664,7 +681,8 @@ class NetServer:
                 if self._closing:
                     conn.close()
                     continue
-                session = _Session(conn, on_drop=self._record_drop)
+                session = _Session(conn, on_drop=self._record_drop,
+                                   clock=self.loop.clock)
                 self._conns.add(session)
             threading.Thread(target=self._serve_conn, args=(session,),
                              name="serve-conn", daemon=True).start()
@@ -716,6 +734,8 @@ class NetServer:
 
     def _serve_conn(self, session: _Session) -> None:
         conn = session.sock
+        clock = self.loop.clock
+        tracer = getattr(self.loop.backend, "tracer", None)
         self.metrics.record_connection(+1)
         v2 = self.proto_version >= 2
         v3 = self.proto_version >= 3
@@ -738,7 +758,15 @@ class NetServer:
                     raise ConnectionError(
                         f"unexpected message "
                         f"{payload[:1].hex() or 'empty'}")
+                # traced, the frame's decode is its request's first span
+                # and the trace starts when the frame arrived
+                traced = tracer is not None and tracer.enabled
+                if traced:
+                    t_frame = clock()
                 rid, terms, th, top_k, dl, tid = decode_query(payload)
+                pre_spans = ()
+                if traced:
+                    pre_spans = (("decode", t_frame, clock()),)
                 deadline = (None if dl is None
                             else self.loop.clock() + dl)
                 # the trace block goes back only when the CLIENT asked
@@ -747,13 +775,20 @@ class NetServer:
 
                 def on_done(resp: QueryResponse, rid=rid,
                             tid=tid) -> None:
-                    session.send(encode_result(rid, resp, trace_id=tid))
+                    trace = resp.trace
+                    if trace is None:
+                        session.send(encode_result(rid, resp, trace_id=tid))
+                        return
+                    t0 = clock()
+                    frame = encode_result(rid, resp, trace_id=tid)
+                    trace.add("encode", t0, clock())
+                    session.send(frame, trace)
 
                 try:
                     self.loop.submit(terms=terms, threshold=th,
                                      top_k=top_k or None,
                                      deadline=deadline, trace_id=tid,
-                                     on_done=on_done)
+                                     pre_spans=pre_spans, on_done=on_done)
                 except LoopClosed:
                     # shutting down: 429-style refusal, session stays up
                     # until the client closes or the server finishes

@@ -35,8 +35,7 @@ from ..core.query import (PruneStats, SearchResult, compile_pattern,
                           run_paged_compressed, run_paged_dedup,
                           run_paged_pruned, select_hits, select_top_k)
 from ..kernels.autotune import KernelTuner, TuningCache
-from ..obs import EventLog, KernelProfiler, Tracer
-from ..obs.profile import gather_bytes
+from ..obs import EventLog, KernelProfiler, Tracer, span
 from .base import ServingBackend
 from .batcher import MicroBatch, MicroBatcher
 from .cache import LRUCache, result_key, term_key
@@ -107,9 +106,9 @@ class ServerConfig:
     trace_ring: int = 256
     # JSONL slow-query log path; None keeps events in memory only.
     trace_log: Optional[str] = None
-    # Per-dispatch kernel wall time + bytes-moved accounting, fed to the
-    # metrics registry and (when a tuner is wired) back into the tuning
-    # cache as live observed-cost entries.
+    # Per-dispatch kernel wall time, fed to the metrics registry and
+    # (when a tuner is wired) back into the tuning cache as live
+    # observed-cost entries.
     profile_kernels: bool = True
 
 
@@ -175,9 +174,9 @@ class QueryServer(ServingBackend):
         self.profiler = KernelProfiler(self.metrics.registry, self.tuner,
                                        enabled=config.profile_kernels)
         # Tile-cache events flow through one observer: per-shard labeled
-        # counters always; per-batch fault/prefetch capture so the kernel
-        # span can name the shards it had to stage.
-        self._tile_events: list[tuple] = []
+        # counters always; a traced batch's stagings as tile_fetch spans
+        # of the batch being scored.
+        self._batch_rec = None
         self.tiles.observer = self._on_tile_event
         # Compressed-arena accounting: host-side decodes land in the
         # decode histogram; staged bytes are read as per-batch deltas of
@@ -189,22 +188,27 @@ class QueryServer(ServingBackend):
     def _on_tile_event(self, shard: int, event: str,
                        seconds: float) -> None:
         self.metrics.record_shard_tile(shard, event)
-        if event in ("fault", "prefetch"):
-            self._tile_events.append((shard, event, self.clock(), seconds))
+        rec = self._batch_rec
+        if rec is not None and event in ("fault", "prefetch"):
+            now = self.clock()
+            rec.add("tile_fetch", now - seconds, now, shard=shard,
+                    event=event)
 
     # -- submission ---------------------------------------------------------
     def submit(self, pattern=None, *, terms: Optional[np.ndarray] = None,
                threshold: Optional[float] = None,
                top_k: Optional[int] = None,
                deadline: Optional[float] = None,
-               trace_id: int = 0) -> int:
+               trace_id: int = 0, pre_spans: tuple = ()) -> int:
         """Accept one query (pattern or precompiled terms); returns the
         request id. ``top_k`` switches the request from coverage-threshold
         selection to exact top-k (same total order as QueryEngine.top_k).
         Fast paths answer immediately; everything else lands in the
         micro-batcher until the next ``step``/``drain``. ``trace_id``
         propagates a caller-minted id (the wire layer's) into the
-        request's trace; 0 mints a fresh one when tracing is on."""
+        request's trace; 0 mints a fresh one when tracing is on.
+        ``pre_spans`` are the (name, start, end) stages the request passed
+        before it got here (``Tracer.begin``)."""
         if (pattern is None) == (terms is None):
             raise ValueError("pass exactly one of pattern / terms")
         if terms is None:
@@ -217,7 +221,7 @@ class QueryServer(ServingBackend):
         self._next_id += 1
         ell = terms.shape[0]
         trace = self.tracer.begin(rid, trace_id=trace_id or None,
-                                  started_s=now)
+                                  started_s=now, spans=pre_spans)
 
         if ell == 0:
             empty = SearchResult(np.zeros(0, np.int32),
@@ -311,92 +315,90 @@ class QueryServer(ServingBackend):
         return select_hits(scores, n_terms, threshold)
 
     # -- batch scoring -------------------------------------------------------
+    def _score_dense(self, fn, fn_comp, args, rec) -> np.ndarray:
+        """One dispatch against the resident arena (tile 0; its cached
+        device copy serves every backend, so a single-shard MappedArena
+        is not re-uploaded per batch). With ``fn_comp`` (compressed plans)
+        a dict-coded arena scores its (dict, refs) form through the
+        fused-decode kernels."""
+        comp = (fn_comp is not None and self.index.storage.shard_codec(0)
+                in _codec.DICT_CODECS)
+        with span(rec, "tile_get"):
+            tile = (self.tiles.get_compressed(0) if comp
+                    else (self.tiles.get(0),))
+        with span(rec, "dispatch"):
+            out = (fn_comp if comp else fn)(*tile, *args)
+        with span(rec, "readback"):
+            return np.asarray(out)
+
     def _run_plan(self, plan, fn, terms_dev, valid_dev,
-                  fn_comp=None) -> np.ndarray:
+                  fn_comp=None, rec=None) -> np.ndarray:
         """Dispatch ``fn`` once against the dense arena, or — for a paged
         plan — once per shard tile (staged through the LRU tile cache),
         concatenating per-shard slot scores along the slot axis. With
         ``fn_comp`` (compressed plans) dict-coded shards stage their
-        (dict, refs) form and score through the fused-decode kernels."""
+        (dict, refs) form and score through the fused-decode kernels.
+        ``rec`` (a tracing BatchRecorder, or None) gets the spans."""
         if not plan.paged:
-            # tiles.get(0) caches the device copy for every backend (a
-            # single-shard MappedArena would otherwise re-upload per batch)
-            if (fn_comp is not None and self.index.storage.shard_codec(0)
-                    in _codec.DICT_CODECS):
-                dict_rows, refs = self.tiles.get_compressed(0)
-                out = fn_comp(dict_rows, refs, self.index.row_offset,
-                              self.index.block_width, terms_dev, valid_dev)
-            else:
-                out = fn(self.tiles.get(0), self.index.row_offset,
-                         self.index.block_width, terms_dev, valid_dev)
-            return np.asarray(out)
+            return self._score_dense(
+                fn, fn_comp, (self.index.row_offset, self.index.block_width,
+                              terms_dev, valid_dev), rec)
         if fn_comp is not None:
-            return np.concatenate(
-                run_paged_compressed(self.tiles, self._shard_args, fn,
-                                     fn_comp, terms_dev, valid_dev),
-                axis=-1)
-        return np.concatenate(
-            run_paged(self.tiles, self._shard_args, fn, terms_dev,
-                      valid_dev), axis=-1)
+            return run_paged_compressed(self.tiles, self._shard_args, fn,
+                                        fn_comp, terms_dev, valid_dev,
+                                        rec=rec)
+        return run_paged(self.tiles, self._shard_args, fn, terms_dev,
+                         valid_dev, rec=rec)
 
     def _score_dedup(self, buf: np.ndarray, n_valid: np.ndarray, plan,
-                     marks: Optional[list] = None) -> Optional[np.ndarray]:
+                     rec=None) -> Optional[np.ndarray]:
         """Row-dedup dispatch, or None when the batch's measured dedup
         rate is below the plan's break-even threshold. The global-layout
         plan decides; dense execution reuses it directly, paged execution
-        re-plans per shard against the rebased addressing. ``marks``
-        collects (name, start, end, tags) stage timings for tracing."""
+        re-plans per shard against the rebased addressing."""
         layout = self.index.layout
-        td0 = self.clock()
-        dp = plan_dedup_batch(buf, n_valid, layout.row_offset,
-                              layout.block_width)
-        if marks is not None:
-            marks.append(("dedup_plan", td0, self.clock(),
-                          {"dedup_rate": round(float(dp.dedup_rate), 4),
-                           "n_unique": int(dp.n_unique)}))
+        with span(rec, "dedup_plan") as tags:
+            dp = plan_dedup_batch(buf, n_valid, layout.row_offset,
+                                  layout.block_width)
+        if tags is not None:
+            tags.update(dedup_rate=round(float(dp.dedup_rate), 4),
+                        n_unique=int(dp.n_unique))
         if dp.dedup_rate < plan.dedup_threshold:
             return None
+        method = "dedup_c" if plan.compressed else "dedup"
         fn = self.planner.dedup_score_fn(plan)
         fn_comp = (self.planner.comp_dedup_score_fn(plan)
                    if plan.compressed else None)
         tk0 = self.clock()
-        if not plan.paged:
-            planned = (jnp.asarray(dp.uniq_rows), jnp.asarray(dp.indir),
-                       jnp.asarray(dp.mask))
-            if (fn_comp is not None and self.index.storage.shard_codec(0)
-                    in _codec.DICT_CODECS):
-                dict_rows, refs = self.tiles.get_compressed(0)
-                slots = np.asarray(fn_comp(dict_rows, refs, *planned))
+        with self._kernel_span(rec, method, plan):
+            if not plan.paged:
+                with span(rec, "upload"):
+                    planned = (jnp.asarray(dp.uniq_rows),
+                               jnp.asarray(dp.indir), jnp.asarray(dp.mask))
+                slots = self._score_dense(fn, fn_comp, planned, rec)
             else:
-                slots = np.asarray(fn(self.tiles.get(0), *planned))
-        else:
-            slots = run_paged_dedup(self.tiles, self.planner.shard_plans,
-                                    fn, buf, n_valid, fn_comp=fn_comp)
-        tk1 = self.clock()
-        self._kernel_mark(marks, "dedup_c" if plan.compressed else "dedup",
-                          plan, tk0, tk1, rows=int(dp.uniq_rows.shape[0]))
+                slots = run_paged_dedup(self.tiles, self.planner.shard_plans,
+                                        fn, buf, n_valid, fn_comp=fn_comp,
+                                        rec=rec)
+        self._profile(method, plan, self.clock() - tk0)
         return slots
 
-    def _kernel_mark(self, marks: Optional[list], method: str, plan,
-                     t0: float, t1: float, *, rows: int) -> None:
-        """Record one kernel dispatch: trace mark (with the shards the
-        tile cache had to stage mid-dispatch), profiler histogram, and
-        the live cost signal for the autotuner."""
-        moved = gather_bytes(rows, int(self.index.storage.shape[1]))
-        if marks is not None:
-            tags = {"method": method, "bucket": plan.bucket,
-                    "word_block": plan.word_block or 0,
-                    "bytes_moved": moved}
-            faulted = sorted({s for s, ev, _, _ in self._tile_events
-                              if ev == "fault"})
-            if faulted:
-                tags["faulted_shards"] = faulted
-            marks.append(("kernel_score", t0, t1, tags))
+    @staticmethod
+    def _kernel_span(rec, method: str, plan):
+        """The ``kernel_score`` span of one scoring dispatch, from before
+        its inputs go to the device until its scores are on the host."""
+        if rec is None:
+            return span(None, "kernel_score")
+        return rec.span("kernel_score", method=method, bucket=plan.bucket,
+                        word_block=plan.word_block or 0)
+
+    def _profile(self, method: str, plan, seconds: float) -> None:
+        """One kernel dispatch into the profiler's histogram and the live
+        cost signal for the autotuner."""
         self.profiler.record(
             method=method, bucket=plan.bucket, batch=plan.batch_size,
-            seconds=t1 - t0, word_block=plan.word_block or 0,
-            term_block=plan.term_block or 0, grid_order=plan.grid_order,
-            bytes_moved=moved)
+            seconds=seconds, word_block=plan.word_block or 0,
+            term_block=plan.term_block or 0, grid_order=plan.grid_order)
 
     def score_batch(self, batch: MicroBatch) -> None:
         """Plan, dispatch, and answer one flushed micro-batch. Public so
@@ -404,11 +406,8 @@ class QueryServer(ServingBackend):
         ``poll_batches`` and score them from worker threads."""
         t0 = self.clock()
         Q, B = batch.size, batch.bucket
-        traced = any(r.trace is not None for r in batch.requests)
-        marks: Optional[list] = [] if traced else None
-        self._tile_events = []
-        nb = self.index.layout.n_blocks
-        tp0 = self.clock()
+        rec = self.tracer.batch(batch.requests)
+        self._batch_rec = rec
         # The weakest coverage threshold across the batch is the bound
         # every block must clear for at least one request — the planner's
         # basis for predicting the prune rate. All-top-k batches pass
@@ -416,12 +415,11 @@ class QueryServer(ServingBackend):
         # static prediction the planner stays unpruned).
         thr_hint = min((r.threshold for r in batch.requests if not r.top_k),
                        default=None)
-        plan = self.planner.plan(B, Q, threshold=thr_hint)
-        if marks is not None:
-            marks.append(("plan", tp0, self.clock(),
-                          {"method": plan.method, "fused": int(plan.fused),
-                           "paged": int(plan.paged),
-                           "pruned": int(plan.pruned)}))
+        with span(rec, "plan") as tags:
+            plan = self.planner.plan(B, Q, threshold=thr_hint)
+        if tags is not None:
+            tags.update(method=plan.method, fused=int(plan.fused),
+                        paged=int(plan.paged), pruned=int(plan.pruned))
         # compressed fused dispatch reports (and live-profiles) as
         # "lookup_c" — the tuner's cost key for the decode-in-the-loop
         # kernel, keeping observed costs per path
@@ -452,28 +450,28 @@ class QueryServer(ServingBackend):
             method = "lookup_p"
             pstats = PruneStats()
             tk0 = self.clock()
-            slots = run_paged_pruned(
-                self.tiles, self.planner.shard_plans, buf, n_valid,
-                required, topks, n_hashes=self.index.params.n_hashes,
-                chunk_terms=plan.chunk_terms or self.config.prune_chunk,
-                word_block=plan.word_block, stats=pstats)
-            tk1 = self.clock()
-            w = int(self.index.storage.shape[1])
-            self._kernel_mark(marks, method, plan, tk0, tk1,
-                              rows=max(1, pstats.bytes_read // (4 * w)))
+            with span(rec, "prune") as tags:
+                slots = run_paged_pruned(
+                    self.tiles, self.planner.shard_plans, buf, n_valid,
+                    required, topks, n_hashes=self.index.params.n_hashes,
+                    chunk_terms=plan.chunk_terms or self.config.prune_chunk,
+                    word_block=plan.word_block, stats=pstats, rec=rec)
+            self._profile(method, plan, self.clock() - tk0)
             self.metrics.record_prune(
                 blocks_total=pstats.blocks_total,
                 blocks_pruned=pstats.blocks_pruned,
                 tiles_skipped=pstats.shard_visits_skipped,
                 bytes_saved=max(
-                    0, self._arena_total_bytes - pstats.bytes_read))
-            if marks is not None:
-                marks.append(("prune", tk0, tk1, {
-                    "blocks_pruned": int(pstats.blocks_pruned),
-                    "blocks_total": int(pstats.blocks_total),
-                    "tiles_skipped": int(pstats.shard_visits_skipped),
-                    "bytes_read": int(pstats.bytes_read),
-                    "predicted": round(float(plan.predicted_prune), 3)}))
+                    0, self._arena_total_bytes - pstats.bytes_read),
+                syncs=pstats.syncs)
+            if tags is not None:
+                tags.update(
+                    blocks_pruned=int(pstats.blocks_pruned),
+                    blocks_total=int(pstats.blocks_total),
+                    tiles_skipped=int(pstats.shard_visits_skipped),
+                    bytes_read=int(pstats.bytes_read),
+                    syncs=int(pstats.syncs),
+                    predicted=round(float(plan.predicted_prune), 3))
             scores = slots[:Q][:, self._host_slot]
         elif Q == 1:
             buf = np.zeros((B, 2), dtype=np.uint32)
@@ -482,10 +480,12 @@ class QueryServer(ServingBackend):
             fn_comp = (self.planner.comp_single_score_fn(plan)
                        if plan.compressed else None)
             tk0 = self.clock()
-            slots = self._run_plan(plan, fn, jnp.asarray(buf),
-                                   jnp.int32(ells[0]), fn_comp=fn_comp)
-            self._kernel_mark(marks, method, plan, tk0, self.clock(),
-                              rows=B * nb)
+            with self._kernel_span(rec, method, plan):
+                with span(rec, "upload"):
+                    inputs = (jnp.asarray(buf), jnp.int32(ells[0]))
+                slots = self._run_plan(plan, fn, *inputs, fn_comp=fn_comp,
+                                       rec=rec)
+            self._profile(method, plan, self.clock() - tk0)
             scores = slots[None, self._host_slot]
         else:
             # Pad the query axis to a power of two so jit entries stay
@@ -499,7 +499,7 @@ class QueryServer(ServingBackend):
             n_valid[:Q] = ells
             slots = None
             if plan.fused and plan.dedup_threshold is not None:
-                slots = self._score_dedup(buf, n_valid, plan, marks)
+                slots = self._score_dedup(buf, n_valid, plan, rec)
                 if slots is not None:
                     method = "dedup_c" if plan.compressed else "dedup"
             if slots is None:
@@ -507,21 +507,18 @@ class QueryServer(ServingBackend):
                 fn_comp = (self.planner.comp_batch_score_fn(plan)
                            if plan.compressed else None)
                 tk0 = self.clock()
-                slots = self._run_plan(plan, fn, jnp.asarray(buf),
-                                       jnp.asarray(n_valid),
-                                       fn_comp=fn_comp)
-                self._kernel_mark(marks, method, plan, tk0, self.clock(),
-                                  rows=q_pad * nb * B)
+                with self._kernel_span(rec, method, plan):
+                    with span(rec, "upload"):
+                        inputs = (jnp.asarray(buf), jnp.asarray(n_valid))
+                    slots = self._run_plan(plan, fn, *inputs,
+                                           fn_comp=fn_comp, rec=rec)
+                self._profile(method, plan, self.clock() - tk0)
             scores = slots[:Q][:, self._host_slot]
         t1 = self.clock()
         service = t1 - t0
+        self._batch_rec = None
+        spans = rec.finished() if rec is not None else ()
 
-        if marks is not None:
-            # tile stagings observed during this batch's dispatches, as
-            # their own spans naming the shard (demand fault vs prefetch)
-            for s, ev, t_end, dur in self._tile_events:
-                marks.append(("tile_fetch", t_end - dur, t_end,
-                              {"shard": s, "event": ev}))
         self.planner.record(plan, method)
         self.metrics.record_batch(Q, self.batcher.occupancy(batch), method)
         self.metrics.record_arena_bytes(
@@ -547,8 +544,7 @@ class QueryServer(ServingBackend):
                 r.trace.add("queue_wait", r.submitted_at, t0,
                             {"flush": batch.reason or "direct",
                              "batch_size": Q})
-                for name, ms, me, tags in marks:
-                    r.trace.add(name, ms, me, tags)
+                r.trace.extend(spans)
                 r.trace.add("select", ts0, self.clock())
                 self.finalize_trace(r.trace, resp)
             self._responses[r.request_id] = resp

@@ -293,15 +293,10 @@ class ShardWorker:
                                                n_valid_dev)
         slots = np.asarray(slots)
         if self.profiler is not None:
-            from ..obs.profile import gather_bytes
-            nb_local = int(getattr(plan.row_offset, "shape", (1,))[0])
             self.profiler.record(
                 method=method, bucket=bucket, batch=q,
                 seconds=time.perf_counter() - t0,
-                word_block=wb or 0,
-                bytes_moved=gather_bytes(q * nb_local * bucket,
-                                         int(self.storage.shape[1])),
-                shard=gshard)
+                word_block=wb or 0, shard=gshard)
         return slots, plan, method
 
     def _check_cancel(self, cancelled) -> None:
@@ -397,7 +392,6 @@ class ShardWorker:
         required = np.full(Q, np.iinfo(np.int32).max, dtype=np.int64)
         for i in range(n_live):
             required[i] = 0 if topks[i] else int(cutoffs[i])
-        bytes0 = self.prune_stats.bytes_read
         t0 = time.perf_counter()
         slots = run_paged_pruned(
             self.tiles, [plan], np.asarray(terms_dev), n_valid, required,
@@ -408,7 +402,5 @@ class ShardWorker:
             self.profiler.record(
                 method="lookup_p", bucket=bucket, batch=Q,
                 seconds=time.perf_counter() - t0,
-                word_block=self.word_block or 0,
-                bytes_moved=self.prune_stats.bytes_read - bytes0,
-                shard=gshard)
+                word_block=self.word_block or 0, shard=gshard)
         return slots, plan, "lookup_p"

@@ -26,7 +26,6 @@ from repro.obs import (EventLog, KernelProfiler, MetricsRegistry, Trace,
                        Tracer, render_prometheus)
 from repro.obs.events import read_jsonl
 from repro.obs.export import parse_prometheus
-from repro.obs.profile import gather_bytes
 from repro.serve import (Frontend, FrontendConfig, NetClient, NetServer,
                          QueryServer, ServerConfig, ServingLoop,
                          ServingMetrics, ShardWorker, Status)
@@ -193,14 +192,15 @@ def test_profiler_records_into_registry():
     prof = KernelProfiler(reg, None)
     for i in range(3):
         prof.record(method="fused", bucket=64, batch=8,
-                    seconds=0.001 * (i + 1), word_block=8,
-                    bytes_moved=gather_bytes(8, 16), shard=2)
+                    seconds=0.001 * (i + 1), word_block=8, shard=2)
     assert prof.count == 3
     assert prof.records()[-1]["shard"] == 2
+    assert "bytes_moved" not in prof.records()[-1]
     hist = reg.get("kernel_score_seconds").labels("fused", 64, 8)
     assert hist.count == 3
-    assert reg.get("kernel_bytes_moved_total").labels(
-        "fused", 64).value == 3 * 8 * 16 * 4
+    assert hist.sum == pytest.approx(0.006)
+    # arena bytes are counted by the benchmark's roofline, not here
+    assert reg.get("kernel_bytes_moved_total") is None
     # disabled profiler is a no-op
     off = KernelProfiler(reg, None, enabled=False)
     off.record(method="fused", bucket=64, batch=8, seconds=1.0)
@@ -383,10 +383,13 @@ def test_socket_trace_matches_server_slow_log(built, tmp_path):
     for s in ev["spans"]:
         assert ev["started_s"] <= s["start_s"] <= s["end_s"]
         assert s["end_s"] <= ev["ended_s"] + 1e-9
-    # and the server-side ring has the same sealed trace
+    # and the server-side ring has the same sealed trace; the writer
+    # thread may add the reply's outbox_wait and write after the seal
     trace = server.tracer.find(r.trace_id)
     assert trace is not None and trace.done
-    assert trace.stage_totals().keys() == by_stage.keys()
+    assert trace.stage_totals().keys() - by_stage.keys() <= {
+        "outbox_wait", "write"}
+    assert by_stage.keys() <= trace.stage_totals().keys()
 
 
 def test_stats_snapshot_counts_traces(built):
