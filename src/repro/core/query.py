@@ -173,16 +173,45 @@ def select_top_k(scores: np.ndarray, n_terms: int, k: int) -> "SearchResult":
     return SearchResult(order.astype(np.int32), top, n_terms, int(top[-1]))
 
 
+@jax.jit
+def _join_slots(*parts):
+    """``concatenate(parts, axis=-1)`` as ONE fused device op: each part
+    is zero-padded to its place along the slot axis and OR-ed in. On TPU
+    a concatenate of tiled parts compiles to one dynamic-update-slice and
+    one pair of async copies per part (24 device ops for 8 shards)."""
+    total, lo, out = sum(p.shape[-1] for p in parts), 0, None
+    for p in parts:
+        w = p.shape[-1]
+        y = jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(lo, total - lo - w)])
+        out, lo = y if out is None else out | y, lo + w
+    return out
+
+
+def read_back(parts: list, rec=None) -> np.ndarray:
+    """One batch's per-shard slot scores on the host: joined on their
+    device along the slot axis, then copied back in ONE blocking transfer
+    rather than one per shard (each copy costs a transfer's latency, and
+    the device idles through them). A single part skips the join. The
+    ``readback`` span is tagged with ``copies``, the device->host
+    transfers the batch waited on."""
+    with span(rec, "readback") as tags:
+        if tags is not None:
+            tags["copies"] = 1
+        return np.asarray(parts[0] if len(parts) == 1
+                          else _join_slots(*parts))
+
+
 def run_paged(tiles, shard_args, fn, *args, rec=None) -> np.ndarray:
     """Dispatch ``fn`` once per shard tile with double-buffered prefetch,
-    shared by the QueryEngine and the serving QueryServer, and concatenate
-    the per-shard slot scores along the last axis.
+    shared by the QueryEngine and the serving QueryServer, and join the
+    per-shard slot scores along the last axis.
 
     While shard i's scoring call is in flight (jax dispatch is async),
     shard i+1 stages host->device through ``tiles.prefetch`` — transfer
-    overlaps compute. Results are forced to host only after every dispatch
-    is issued. ``shard_args`` is [(shard, row_offset_dev, block_width_dev)]
-    and ``fn(tile, offs, widths, *args)`` the planned scorer. ``rec`` (a
+    overlaps compute. Once every dispatch is issued the per-shard results
+    are joined on device and read back once (``read_back``).
+    ``shard_args`` is [(shard, row_offset_dev, block_width_dev)] and
+    ``fn(tile, offs, widths, *args)`` the planned scorer. ``rec`` (a
     tracing BatchRecorder, or None) times each shard's ``tile_get`` (its
     prefetch and get), each ``dispatch`` and the final ``readback``."""
     parts = []
@@ -196,8 +225,7 @@ def run_paged(tiles, shard_args, fn, *args, rec=None) -> np.ndarray:
             with span(rec, "tile_get"):
                 tiles.prefetch(nxt)
                 tile = tiles.get(nxt)
-    with span(rec, "readback"):
-        return np.concatenate([np.asarray(p) for p in parts], axis=-1)
+    return read_back(parts, rec)
 
 
 def run_paged_compressed(tiles, shard_args, fn_raw, fn_comp, *args,
@@ -207,7 +235,8 @@ def run_paged_compressed(tiles, shard_args, fn_raw, fn_comp, *args,
     ``fn_comp(dict_rows, refs, offs, widths, *args)`` — the fused-decode
     kernels — while raw shards take ``fn_raw`` unchanged. Prefetch is
     codec-aware, so the overlap stages the form that will actually be
-    scored. Outputs are bit-identical to the all-raw path."""
+    scored. The per-shard results are joined on device and read back once,
+    as in ``run_paged``. Outputs are bit-identical to the all-raw path."""
     storage = tiles.storage
     comp = [storage.shard_codec(s) in _codec.DICT_CODECS
             for (s, _, _) in shard_args]
@@ -228,8 +257,7 @@ def run_paged_compressed(tiles, shard_args, fn_raw, fn_comp, *args,
                 (tiles.prefetch_compressed if comp[i + 1]
                  else tiles.prefetch)(shard_args[i + 1][0])
                 tile = get(i + 1)
-    with span(rec, "readback"):
-        return np.concatenate([np.asarray(p) for p in parts], axis=-1)
+    return read_back(parts, rec)
 
 
 # --------------------------------------------------------------------------
@@ -356,9 +384,10 @@ def run_paged_dedup(tiles, shard_plans: list[ShardPlan], fn,
     for dense storage): per shard, plan the unique-row set against the
     shard's REBASED addressing, score through ``fn`` (from
     ``make_dedup_score_fn``), prefetch the next tile while the dispatch is
-    in flight, and concatenate per-shard slot scores — the dedup analogue
-    of ``run_paged``, recording the same spans into ``rec`` and each
-    shard's planned rows going to the device as ``upload``.
+    in flight, and join the per-shard slot scores on device for one
+    read-back (``read_back``) — the dedup analogue of ``run_paged``,
+    recording the same spans into ``rec`` and each shard's planned rows
+    going to the device as ``upload``.
 
     With ``fn_comp`` (from ``make_comp_dedup_score_fn``) dict-coded shards
     stage compressed and score through the fused-decode kernels; raw
@@ -384,8 +413,7 @@ def run_paged_dedup(tiles, shard_plans: list[ShardPlan], fn,
             with span(rec, "tile_get"):
                 (tiles.prefetch_compressed if comp[i + 1]
                  else tiles.prefetch)(nxt)
-    with span(rec, "readback"):
-        return np.concatenate([np.asarray(p) for p in parts], axis=1)
+    return read_back(parts, rec)
 
 
 # --------------------------------------------------------------------------

@@ -31,8 +31,8 @@ from ..core import hashing
 from ..core.arena import DeviceTileCache, common_tile_rows
 from ..core.index import BitSlicedIndex
 from ..core.query import (PruneStats, SearchResult, compile_pattern,
-                          coverage_cutoff, plan_dedup_batch, run_paged,
-                          run_paged_compressed, run_paged_dedup,
+                          coverage_cutoff, plan_dedup_batch, read_back,
+                          run_paged, run_paged_compressed, run_paged_dedup,
                           run_paged_pruned, select_hits, select_top_k)
 from ..kernels.autotune import KernelTuner, TuningCache
 from ..obs import EventLog, KernelProfiler, Tracer, span
@@ -328,8 +328,7 @@ class QueryServer(ServingBackend):
                     else (self.tiles.get(0),))
         with span(rec, "dispatch"):
             out = (fn_comp if comp else fn)(*tile, *args)
-        with span(rec, "readback"):
-            return np.asarray(out)
+        return read_back([out], rec)
 
     def _run_plan(self, plan, fn, terms_dev, valid_dev,
                   fn_comp=None, rec=None) -> np.ndarray:

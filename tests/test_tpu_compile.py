@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.query import _pad_unique
+from repro.core.query import _join_slots, _pad_unique
 from repro.kernels import bitslice_score as k
 from repro.kernels import ops
 
@@ -130,3 +130,16 @@ def test_compressed_lookup_compiles(one_chip):
 def test_vertical_compiles(one_chip):
     _compile(ops.bitslice_score, _spec(one_chip, (L, NB * W), jnp.uint32),
              method="vertical")
+
+
+@pytest.mark.parametrize("shapes", [[(1024,)] * 8, [(Q, 1024)] * 8,
+                                    [(8, 1024)] * 7 + [(8, 512)]],
+                         ids=["one-query", "padded-batch", "short-last"])
+def test_slot_join_is_one_device_op(one_chip, shapes):
+    """A paged batch's per-shard scores are joined by one fused op: every
+    device op adds an event, and an idle gap, to each batch's trace."""
+    c = _join_slots.lower(*[_spec(one_chip, s) for s in shapes]).compile()
+    entry = c.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    ops_run = [ln for ln in entry.splitlines()[1:]
+               if " parameter(" not in ln]
+    assert len(ops_run) == 1 and " fusion(" in ops_run[0], ops_run

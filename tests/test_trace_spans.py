@@ -117,6 +117,9 @@ def test_each_branch_records_its_batch_spans_once(corpus_stores, branch):
         else:
             assert {kids[n] for n in KERNEL - {"plan", "kernel_score"}} \
                 == {"kernel_score"}
+            # the shards' scores come back in one transfer, dense or paged
+            (back,) = [s for s in spans if s.name == "readback"]
+            assert back.tags["copies"] == 1
     if branch == "pruned":
         syncs = [s.tags["syncs"] for spans in _batch_spans(traces).values()
                  for s in spans if s.name == "prune"]
