@@ -72,14 +72,22 @@ class DeviceTrace:
         """Idle seconds per name of the host span covering most of each
         gap, the shortest such span where several cover it alike (a
         batch's ``prune`` over its requests' ``queue_wait``), and
-        "no_request" where none does; the ``n`` largest."""
+        "no_request" where none does; the ``n`` largest.
+
+        One sweep: the gaps are disjoint and in time order, so the spans
+        are taken on in start order as gaps reach them, and dropped once
+        they end before a gap starts. The spans still open keep start
+        order, so a full tie goes to the first of them."""
         spans = sorted(set(spans), key=lambda sp: sp[1])
         tot: dict[str, float] = {}
+        open_, i = [], 0
         for a, b in self.idle_gaps():
+            while i < len(spans) and spans[i][1] < b:
+                open_.append(spans[i])
+                i += 1
+            open_ = [sp for sp in open_ if sp[2] > a]
             best, name = (0.0, 0.0), "no_request"
-            for sname, s, e in spans:
-                if s >= b:
-                    break
+            for sname, s, e in open_:
                 key = (min(b, e) - max(a, s), s - e)
                 if key[0] > 0 and key > best:
                     best, name = key, sname

@@ -4,7 +4,8 @@
     python3 bench/run.py --workload CELL --seed N --seconds S --trace 0|1
 
 The cell names a configuration (``bench/configs/<config>.json``: the
-documents, the store and the ``ServerConfig``) and a traffic mix
+documents, the store, the ``ServerConfig`` and, for an index sharded over
+the chips of one host, a ``frontend``) and a traffic mix
 (``bench/traffic/<cell>.json``: the loop, the transport and the query
 mix). Its metrics are the end-to-end ones of ``BENCHMARK.json`` with
 ``--trace 0`` and the per-layer ones with ``--trace 1``, each computed by
@@ -108,15 +109,80 @@ def devices(chips: int, require_tpu: bool):
     return devs
 
 
-def memory_peak(devs) -> int:
-    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-             for d in devs]
-    return max(peaks)
+def memory_peaks(devs) -> dict[int, int]:
+    """Each device's ``peak_bytes_in_use``, by device id."""
+    return {int(d.id): int((d.memory_stats() or {}).get("peak_bytes_in_use",
+                                                          0))
+            for d in devs}
+
+
+# -- the server ---------------------------------------------------------------
+
+def check_hosts(config: dict, cell: dict, devs) -> None:
+    """A sharded configuration puts each host on a chip of its own: refuse
+    one with more hosts than the cell's chips or the devices JAX sees
+    (the program would stack several hosts on one chip)."""
+    fe = config.get("frontend")
+    if fe is None:
+        return
+    hosts = int(fe["hosts"])
+    if hosts > int(cell["chips"]):
+        raise SystemExit(f"configuration {cell['config']!r} has {hosts} "
+                         f"hosts; cell {cell['name']!r} asks for "
+                         f"{cell['chips']} chip(s)")
+    if hosts > len(devs):
+        raise NoChip(f"configuration {cell['config']!r} has {hosts} hosts; "
+                     f"JAX sees {len(devs)} device(s)")
+
+
+def build_server(config: dict, index, store_dir: Path, traced: bool,
+                 trace_ring: int):
+    """The configuration's server: a ``QueryServer`` over the index, or,
+    with a ``frontend`` key (``hosts``, ``replication``), the program's
+    in-process ``Frontend`` over one ``ShardWorker`` per host, host i's
+    tiles on device i, with ``config["server"]``'s serving knobs. Whatever
+    the configuration leaves unset is the program's default."""
+    from repro.serve import QueryServer, ServerConfig
+    fe = config.get("frontend")
+    if fe is None:
+        return QueryServer(index, ServerConfig(
+            **config["server"], tracing=traced, trace_ring=trace_ring))
+    from repro.launch.serve import make_multihost_frontend
+    from repro.obs import Tracer
+    from repro.serve.frontend import FrontendConfig
+    d = FrontendConfig()
+    kw = {"max_batch": d.max_batch, "max_wait_s": d.max_wait_s,
+          "hedge_after_s": d.hedge_after_s, **config["server"]}
+    frontend = make_multihost_frontend(
+        store_dir, hosts=int(fe["hosts"]),
+        replication=int(fe["replication"]), tracing=traced, **kw)
+    if traced:
+        # make_multihost_frontend keeps FrontendConfig's ring of 256
+        # traces; the run's readers need every request's
+        frontend.tracer = Tracer(enabled=True, ring=trace_ring,
+                                 slow_ms=frontend.tracer.slow_ms,
+                                 sink=frontend.events, clock=frontend.clock)
+        frontend.metrics.tracer = frontend.tracer
+    return frontend
+
+
+def placement_of(server) -> dict[int, list[int]] | None:
+    """Device id -> the shard ids whose tiles it holds, for a sharded
+    server; None for one ``QueryServer``."""
+    workers = getattr(server, "workers", None)
+    if workers is None:
+        return None
+    out: dict[int, list[int]] = {}
+    for w in workers.values():
+        out.setdefault(int(w.device.id), []).extend(
+            int(g) for g in w.shard_ids)
+    return out
 
 
 # -- warm-up ------------------------------------------------------------------
 
-def warm_up(server, config: dict, mix: dict, seed: int, order) -> None:
+def warm_up(server, index, config: dict, mix: dict, seed: int, order
+            ) -> None:
     """Run every (bucket, padded batch) shape this mix can flush through
     the server, then empty its caches and counters. A pruned
     configuration also compiles its chunk kernel at every unique-row
@@ -136,18 +202,18 @@ def warm_up(server, config: dict, mix: dict, seed: int, order) -> None:
                               top_k=q.top_k or None)
             server.drain()
     if server.config.pruned:
-        warm_chunk_kernels(server, sizes)
+        warm_chunk_kernels(index, sizes, server.config.prune_chunk,
+                           config["server"].get("word_block"))
     server.pop_responses()
     server.reset_metrics(clear_caches=True)
 
 
-def warm_chunk_kernels(server, sizes) -> None:
+def warm_chunk_kernels(index, sizes, ct: int, word_block) -> None:
     """The pruned executor pads a chunk's unique rows to a power of two
     (at least 8) and its queries to a power of two: compile each pair."""
     import jax.numpy as jnp
     from repro.kernels import ops
-    ct = server.config.prune_chunk
-    w = int(server.index.storage.shape[1])
+    w = int(index.storage.shape[1])
     for q in sizes:
         u = 8
         while u <= max(8, q * ct):
@@ -155,7 +221,7 @@ def warm_chunk_kernels(server, sizes) -> None:
             cells = jnp.zeros((q, 1, ct), jnp.int32)
             _, bmax = ops.bitslice_chunk_score_dedup(
                 jnp.zeros((u, w), jnp.uint32), cells, cells, acc,
-                word_block=server.config.word_block)
+                word_block=word_block)
             np.asarray(bmax)
             u *= 2
 
@@ -195,6 +261,7 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, traced: bool,
     cell, config, traffic = cell_spec(spec, name, traffic_dir)
     traffic = {**traffic, **(traffic_override or {})}
     devs = devices(int(cell["chips"]), require_tpu)
+    check_hosts(config, cell, devs)
     peaks = load_json(BENCH / "peaks.json")
     if require_tpu and devs[0].device_kind not in peaks:
         raise SystemExit(f"no peaks for {devs[0].device_kind!r} in "
@@ -205,8 +272,7 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, traced: bool,
         from repro.compile_cache import enable_compile_cache
         enable_compile_cache()
     events = watch.WindowEvents()
-    from repro.serve import (NetClient, NetServer, QueryServer, ServerConfig,
-                             ServingLoop)
+    from repro.serve import NetClient, NetServer, ServingLoop
     mix = traffic["queries"]
     counts = datagen.term_counts(config)
     layout, order, params = datagen.layout_of(counts, config)
@@ -223,14 +289,15 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, traced: bool,
 
     with tempfile.TemporaryDirectory(prefix="cobs-bench-",
                                      dir=scratch) as tmp:
-        index = datagen.write_store(Path(tmp) / "store", blocks, layout,
-                                    params, config["store"])
+        store_dir = Path(tmp) / "store"
+        index = datagen.write_store(store_dir, blocks, layout, params,
+                                    config["store"])
         arena_bytes = index.storage.nbytes()
         log(f"store written: {arena_bytes} B")
-        server = QueryServer(index, ServerConfig(
-            **config["server"], tracing=traced,
-            trace_ring=n + 4096 if traced else 256))
-        warm_up(server, config, mix, seed, order)
+        server = build_server(config, index, store_dir, traced,
+                              n + 4096 if traced else 256)
+        placement = placement_of(server)
+        warm_up(server, index, config, mix, seed, order)
         log("warmed up")
         loop = ServingLoop(server, workers=1)
         net = client = None
@@ -273,7 +340,8 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, traced: bool,
                 timer.join()
             rec.wait(ANSWER_GRACE_S)
             rec.closed_at = loadgen.clock()
-            mem_peak = memory_peak(devs)
+            mem_by_device = memory_peaks(devs)
+            mem_peak = max(mem_by_device.values())
         finally:
             stalls.stop()
             host.stop()
@@ -300,6 +368,7 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, traced: bool,
     run = SimpleNamespace(
         cell=cell, config=config, traffic=traffic, queries=queries,
         records=rec, setup_s=setup_s, memory_peak_bytes=mem_peak,
+        memory_peak_by_device=mem_by_device, placement=placement,
         arena_bytes=arena_bytes, traces=traces, counters=counters,
         device=device, peak=peak, reference=reference)
     metrics = {}
@@ -316,6 +385,11 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, traced: bool,
            "device": {"platform": devs[0].platform,
                       "kind": devs[0].device_kind, "count": len(devs),
                       "memory_peak_bytes": mem_peak}}
+    if placement is not None:
+        out["device"].update(
+            memory_peak_by_device={str(k): v
+                                   for k, v in mem_by_device.items()},
+            placement={str(k): v for k, v in placement.items()})
     if device is not None:
         out["device"].update(busy_s=device.busy_s, window_s=device.window_s)
         spans = [(s.name, s.start_s, s.end_s) for t in traces

@@ -3,6 +3,7 @@ roofline is taken against."""
 import math
 
 import numpy as np
+import pytest
 from jax._src.profiler import ProfileData
 
 import devtrace
@@ -87,3 +88,64 @@ def test_distinct_rows_counts_each_block_row_once():
     # a one-row block folds them all into one
     assert readings.distinct_rows(codes, [1], 31) == 1
     assert readings.ROW_BYTES == 128
+
+
+def _scan_idle_by_span(trace, spans, n=10):
+    """The reduction as it was: every span that starts before a gap ends,
+    scanned for every gap. The oracle of the one-sweep version."""
+    spans = sorted(set(spans), key=lambda sp: sp[1])
+    tot = {}
+    for a, b in trace.idle_gaps():
+        best, name = (0.0, 0.0), "no_request"
+        for sname, s, e in spans:
+            if s >= b:
+                break
+            key = (min(b, e) - max(a, s), s - e)
+            if key[0] > 0 and key > best:
+                best, name = key, sname
+        tot[name] = tot.get(name, 0.0) + (b - a)
+    return [[k, v] for k, v in sorted(tot.items(),
+                                      key=lambda kv: -kv[1])[:n]]
+
+
+def _random_trace(seed):
+    """Busy intervals on two chips and host spans on a coarse grid, so
+    that overlaps and lengths tie often: nested spans, spans alike but
+    for their name, spans of no length, spans over several gaps."""
+    rng = np.random.default_rng(seed)
+    grid = 0.25e-3
+    t_end = 400 * grid
+    busy = []
+    for _ in range(2):
+        starts = np.sort(rng.integers(0, 400, 40)) * grid
+        busy.append(devtrace._merge(
+            (s, min(t_end, s + rng.integers(1, 6) * grid)) for s in starts))
+    trace = devtrace.DeviceTrace((0.0, t_end), busy, [])
+    names = ["queue_wait", "kernel_score", "dispatch", "readback", "plan"]
+    spans = []
+    for _ in range(int(rng.integers(50, 300))):
+        s = int(rng.integers(-10, 405))
+        length = int(rng.choice([0, 1, 2, 3, 8, 40, 120]))
+        spans.append((str(rng.choice(names)), s * grid,
+                      (s + length) * grid))
+        kind = rng.integers(0, 4)
+        if kind == 0 and length > 2:       # a child inside it
+            c = s + int(rng.integers(0, length))
+            spans.append((str(rng.choice(names)), c * grid,
+                          min(s + length, c + int(rng.integers(0, 3)))
+                          * grid))
+        elif kind == 1:                    # the same interval, renamed
+            spans.append((str(rng.choice(names)), s * grid,
+                          (s + length) * grid))
+        elif kind == 2:                    # the same span twice
+            spans.append(spans[-1])
+    return trace, spans
+
+
+@pytest.mark.parametrize("seed", range(40))
+def test_one_sweep_names_every_gap_as_the_scan_did(seed):
+    trace, spans = _random_trace(seed)
+    assert len(trace.idle_gaps()) > 5
+    for n in (3, 10, 100):
+        assert trace.idle_by_span(spans, n) == \
+            _scan_idle_by_span(trace, spans, n)
