@@ -27,6 +27,8 @@ with row offsets rebased to the shard's first row.
 ``DeviceTileCache`` is the HBM paging policy: a bounded LRU of shard id ->
 device tile with hit/fault counters, shared by the QueryEngine and the
 serving subsystem (which exports the counters as metrics).
+``PackedTileCache`` is its HBM-resident form for a set of shards of
+different heights: one arena holding them all, with no padding per shard.
 """
 from __future__ import annotations
 
@@ -40,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import codec as _codec
+from ..kernels.bitslice_score import SUBLANES, row_geometry
 from ..kernels.ops import PackedArena, pack_lines
 
 
@@ -569,6 +572,11 @@ class DeviceTileCache:
         # to trace spans naming the faulted shard.
         self.observer = None
 
+    def row_base(self, s: int) -> int:
+        """The first row of shard ``s`` in the tile ``get(s)`` returns:
+        0, every shard is a tile of its own."""
+        return 0
+
     def _notify(self, s: int, event: str, seconds: float = 0.0) -> None:
         if self.observer is not None:
             try:
@@ -736,3 +744,95 @@ class DeviceTileCache:
             self._sizes.clear()
             self._prefetched.clear()
             self.resident_bytes = 0
+
+
+class PackedTileCache(DeviceTileCache):
+    """Every shard of ``storage`` staged as ONE lane-packed arena, for a
+    host whose shards all stay resident in HBM.
+
+    The shards' rows are concatenated in storage order, so shard ``s``
+    starts at ``row_base(s)`` and ``get(s)`` returns the whole arena, to
+    be addressed with row offsets rebased by that base. One tile shape per
+    storage, padded only to the lane-packing multiple: shards of very
+    different heights (COBS blocks follow the archive's document sizes)
+    cost their own rows, not the tallest shard's each. Rows stay int32
+    row indices, so an arena may pass 2^31 words.
+
+    The arena stages on the first ``get`` or ``prefetch`` of any shard:
+    one fault (a prefetch, if that staged it), which the observer sees for
+    every shard; later gets are hits. Nothing is ever evicted."""
+
+    _KEY = 0                       # the one entry of the LRU map
+
+    def __init__(self, storage: ArenaStorage, device=None):
+        super().__init__(storage, capacity_bytes=None, device=device)
+
+    def row_base(self, s: int) -> int:
+        return int(self.storage.shard_row_starts[s])
+
+    def __len__(self) -> int:
+        return self.storage.n_shards if self._tiles else 0
+
+    @property
+    def resident_shards(self) -> tuple[int, ...]:
+        return tuple(range(len(self)))
+
+    def _stage(self, s: int) -> PackedArena:
+        """The whole storage, copied once into a host buffer already
+        padded to whole 128-lane lines (so packing it is a reshape), then
+        put on the device; the host buffer is freed once it is there."""
+        st = self.storage
+        rows, w = st.shape
+        ws, per_line, _ = row_geometry(w)
+        pad_rows = -(-rows // (per_line * SUBLANES)) * per_line * SUBLANES
+        host = np.zeros((pad_rows, ws), dtype=np.uint32)
+        starts = st.shard_row_starts
+        for i in range(st.n_shards):
+            host[starts[i]:starts[i + 1], :w] = st.shard_host(i)
+        lines = self._put(pack_lines(host))
+        lines.block_until_ready()
+        return PackedArena(lines, rows, w)
+
+    def _stage_all(self, event: str) -> PackedArena:
+        t0 = time.perf_counter()
+        tile = self._stage(self._KEY)
+        staged_s = time.perf_counter() - t0
+        self._tiles[self._KEY] = tile
+        self._sizes[self._KEY] = tile.nbytes
+        self.resident_bytes += tile.nbytes
+        self.raw_bytes_staged += tile.nbytes
+        for s in range(self.storage.n_shards):
+            self.shard_faults[s] = self.shard_faults.get(s, 0) + 1
+            self._notify(s, event, staged_s)
+        return tile
+
+    def get(self, s: int) -> PackedArena:
+        """The packed arena holding shard ``s`` (from ``row_base(s)``)."""
+        with self._lock:
+            tile = self._tiles.get(self._KEY)
+            if tile is None:
+                self.faults += 1
+                return self._stage_all("fault")
+            self.hits += 1
+            self.shard_hits[s] = self.shard_hits.get(s, 0) + 1
+            if self._KEY in self._prefetched:
+                self._prefetched.discard(self._KEY)
+                self.prefetch_hits += 1
+            self._notify(s, "hit")
+            return tile
+
+    def prefetch(self, s: int) -> bool:
+        """Stage the arena if it is not resident; True if this staged it."""
+        with self._lock:
+            if self._KEY in self._tiles:
+                return False
+            self.faults += 1
+            self.prefetched += 1
+            self._prefetched.add(self._KEY)
+            self._stage_all("prefetch")
+            return True
+
+    def get_compressed(self, s: int) -> tuple:
+        raise ValueError("a packed arena holds raw rows only")
+
+    prefetch_compressed = get_compressed

@@ -597,6 +597,9 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
     offs = [sp.row_offset.astype(np.uint32) for sp in shard_plans]
     wids = [sp.block_width.astype(np.uint32) for sp in shard_plans]
     codecs = [storage.shard_codec(sp.shard) for sp in shard_plans]
+    # a promoted shard's rows in the tile that holds it (a packed arena
+    # holds several shards)
+    bases = [tiles.row_base(sp.shard) for sp in shard_plans]
 
     for c in range(n_chunks):
         stats.chunks += 1
@@ -645,7 +648,8 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
                     stats.bytes_tile_staged += hbm
                 mask = jnp.asarray(live.astype(np.int32))
                 if promoted[s] and k == 1:
-                    idx = jnp.asarray(rows[..., 0].astype(np.int32))
+                    idx = jnp.asarray((rows[..., 0] + bases[s]).astype(
+                        np.int32))
                     if codecs[s] in _codec.DICT_CODECS:
                         kernel = ops.bitslice_chunk_score_multi_comp
                         args = (*resident[s], idx, mask)
@@ -662,7 +666,7 @@ def run_paged_pruned(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
                     uniq, inv = np.unique(cells, axis=0, return_inverse=True)
                     u_idx = np.zeros((_pad_unique(uniq.shape[0]), k),
                                      dtype=np.int32)
-                    u_idx[: uniq.shape[0]] = uniq
+                    u_idx[: uniq.shape[0]] = uniq + bases[s]
                     if codecs[s] in _codec.DICT_CODECS:
                         d, r = resident[s]
                         mat_dev = ops.gather_and_rows_comp(
@@ -906,7 +910,8 @@ def run_shard_major(tiles, shard_plans: list[ShardPlan], terms: np.ndarray,
 
         nb = int(sp.block_end - sp.block_start)
         col0, col1 = sp.block_start * W * 32, sp.block_end * W * 32
-        offs = sp.row_offset.astype(np.uint32)
+        offs = (sp.row_offset.astype(np.int64)
+                + cache.row_base(sp.shard)).astype(np.uint32)
         wids = sp.block_width.astype(np.uint32)
         qc = int(query_chunk) if query_chunk else ops.bulk_query_chunk(
             nb, W, word_block=word_block)

@@ -55,7 +55,8 @@ def tuning_path(path: str | Path) -> Path:
 
 
 def _hash_array(a: np.ndarray) -> str:
-    return hashlib.blake2b(np.ascontiguousarray(a).tobytes(),
+    # hashed in place: a copy would hold a second shard in host memory
+    return hashlib.blake2b(memoryview(np.ascontiguousarray(a)).cast("B"),
                            digest_size=16).hexdigest()
 
 
@@ -75,9 +76,9 @@ def row_popcounts(matrix: np.ndarray, *, rows_per_slab: int = 1 << 16
     blocks early) without ever reading the arena itself."""
     out = np.empty(matrix.shape[0], dtype=np.uint32)
     for r0 in range(0, matrix.shape[0], rows_per_slab):
-        slab = np.ascontiguousarray(matrix[r0:r0 + rows_per_slab])
-        bits = np.unpackbits(slab.view(np.uint8), axis=1)
-        out[r0:r0 + slab.shape[0]] = bits.sum(axis=1, dtype=np.int64)
+        slab = matrix[r0:r0 + rows_per_slab]
+        out[r0:r0 + slab.shape[0]] = np.bitwise_count(slab).sum(
+            axis=1, dtype=np.uint32)
     return out
 
 

@@ -19,6 +19,13 @@ is placed on ``replication`` distinct nodes, node failure flips queries to
 the next-highest replica with zero data movement, and recovery rebuilds
 only the lost node's items (not the whole index).
 
+HRW balances item COUNTS. Items of very different sizes (COBS' blocks
+follow the archive's document-size skew: one 1024-document block of the
+paper's largest samples can hold a third of the arena) need balance by
+weight instead: given ``weights``, placement is largest-first greedy
+(``balanced_replicas``), ties broken and replicas ordered by each item's
+HRW ranking.
+
 This is host-side control-plane logic (pure python, deterministic), used
 by the launcher to assign sub-indexes to pods/hosts; the data planes are
 DistributedIndex (mesh) and the ShardWorker/Frontend pair (multi-host
@@ -29,11 +36,41 @@ from __future__ import annotations
 import hashlib
 from dataclasses import dataclass, field
 from pathlib import Path
+from typing import Optional, Sequence
 
 
 def _weight(item_id: int, node: str) -> int:
     h = hashlib.blake2b(f"{item_id}:{node}".encode(), digest_size=8)
     return int.from_bytes(h.digest(), "big")
+
+
+def _ranking(item_id: int, nodes: Sequence[str]) -> list[str]:
+    return sorted(nodes, key=lambda n: _weight(item_id, n), reverse=True)
+
+
+def balanced_replicas(weights: Sequence[int], nodes: Sequence[str],
+                      replication: int) -> list[list[str]]:
+    """Largest-first greedy placement of weighted items: per item, its
+    ``replication`` nodes in HRW preference order.
+
+    Items go heaviest first, each to the least-loaded nodes (every
+    replica's weight counts against its node), ties broken by the item's
+    HRW ranking. An item heavier than everything else stays alone on its
+    node until the others outgrow it, and with many items lighter than a
+    mean share the fullest node stays near max(the heaviest item, the
+    mean share of the others)."""
+    nodes = list(nodes)
+    r = min(int(replication), len(nodes))
+    w = [int(x) for x in weights]
+    load = dict.fromkeys(nodes, 0)
+    out: list[list[str]] = [[] for _ in w]
+    for g in sorted(range(len(w)), key=lambda g: (-w[g], g)):
+        ranked = _ranking(g, nodes)
+        got = sorted(ranked, key=lambda n: load[n])[:r]   # stable: HRW ties
+        out[g] = [n for n in ranked if n in got]
+        for n in got:
+            load[n] += w[g]
+    return out
 
 
 @dataclass
@@ -44,6 +81,10 @@ class RendezvousPlacement:
     n_items: int
     replication: int = 2
     _down: set[str] = field(default_factory=set)
+    # per-item weights: None is plain HRW (balances item counts); given,
+    # placement by weight (``balanced_replicas``)
+    weights: Optional[Sequence[int]] = None
+    _table: tuple = field(default=(), repr=False)
 
     def __post_init__(self):
         if not self.nodes:
@@ -51,13 +92,21 @@ class RendezvousPlacement:
         if self.replication < 1:
             raise ValueError("replication >= 1")
         self.nodes = list(dict.fromkeys(self.nodes))  # dedupe, keep order
+        if self.weights is not None and len(self.weights) != self.n_items:
+            raise ValueError(f"{len(self.weights)} weights for "
+                             f"{self.n_items} items")
 
     # -- placement ----------------------------------------------------------
     def replicas(self, item_id: int) -> list[str]:
         """All replica nodes for an item, preference order (HRW ranking)."""
-        ranked = sorted(self.nodes, key=lambda n: _weight(item_id, n),
-                        reverse=True)
-        return ranked[: min(self.replication, len(ranked))]
+        if self.weights is None:
+            return _ranking(item_id, self.nodes)[
+                : min(self.replication, len(self.nodes))]
+        key = tuple(self.nodes)
+        if not self._table or self._table[0] != key:
+            self._table = (key, balanced_replicas(
+                self.weights, self.nodes, self.replication))
+        return list(self._table[1][item_id])
 
     def owner(self, item_id: int) -> str:
         """Preferred LIVE node for an item (failover-aware)."""
@@ -153,21 +202,29 @@ class ShardPlacement(RendezvousPlacement):
     dispatch.
     """
 
-    def __init__(self, nodes: list[str], n_shards: int, replication: int = 2):
-        super().__init__(nodes, n_shards, replication)
+    def __init__(self, nodes: list[str], n_shards: int, replication: int = 2,
+                 weights: Optional[Sequence[int]] = None):
+        super().__init__(nodes, n_shards, replication, weights=weights)
 
     @property
     def n_shards(self) -> int:
         return self.n_items
 
     @classmethod
-    def for_store(cls, path, nodes: list[str],
-                  replication: int = 2) -> "ShardPlacement":
-        """Placement over the manifest rows of a v2 store directory."""
+    def for_store(cls, path, nodes: list[str], replication: int = 2,
+                  balance: str = "shards") -> "ShardPlacement":
+        """Placement over the manifest rows of a v2 store directory.
+        ``balance`` "shards" is plain HRW; "bytes" weighs each shard by
+        its manifest row count (``balanced_replicas``)."""
         import json
 
         from ..core.store import FORMAT_V2
+        if balance not in ("shards", "bytes"):
+            raise ValueError(f"balance {balance!r}: 'shards' or 'bytes'")
         manifest = json.loads((Path(path) / "manifest.json").read_text())
         if manifest.get("format") != FORMAT_V2:
             raise ValueError(f"not a {FORMAT_V2} store: {path}")
-        return cls(nodes, len(manifest["shards"]), replication)
+        shards = manifest["shards"]
+        weights = ([s["rows"][1] - s["rows"][0] for s in shards]
+                   if balance == "bytes" else None)
+        return cls(nodes, len(shards), replication, weights=weights)

@@ -189,21 +189,27 @@ def make_multihost_frontend(store_dir, *, hosts: int, replication: int,
                             trace_log=None, pruned: bool = False,
                             prune_chunk: int = 32,
                             prune_min_rate=None,
-                            adaptive_buckets: bool = False) -> Frontend:
-    """Sharded data plane over in-process fake hosts: HRW-place the v2
-    manifest rows, open each host's sub-store, wire the hedging frontend
-    (per-shard dispatches overlap through ``scatter_threads`` in
-    wall-clock mode), and optionally mark hosts down (their shards fail
-    over to replicas). Host i's tiles live on ``jax.devices()[i]``
-    (round-robin when there are fewer devices than hosts), so one process
-    serves an index sharded over every chip it holds."""
+                            adaptive_buckets: bool = False,
+                            balance: str = "shards",
+                            trace_ring: int = 256) -> Frontend:
+    """Sharded data plane over in-process fake hosts: place the v2
+    manifest rows (HRW, balancing shard counts, or with ``balance``
+    "bytes" their rows; ``ShardPlacement.for_store``), open each host's
+    sub-store, wire the hedging frontend (per-shard dispatches overlap
+    through ``scatter_threads`` in wall-clock mode; a traced frontend keeps
+    the last ``trace_ring`` traces), and optionally mark hosts down (their
+    shards fail over to replicas). Host i's tiles live on
+    ``jax.devices()[i]`` (round-robin when there are fewer devices than
+    hosts), so one process serves an index sharded over every chip it
+    holds."""
     import jax
 
     from ..index import ShardPlacement
 
     nodes = [f"host{i}" for i in range(hosts)]
     placement = ShardPlacement.for_store(store_dir, nodes,
-                                         replication=min(replication, hosts))
+                                         replication=min(replication, hosts),
+                                         balance=balance)
     held = placement.replica_assignment()
     devices = jax.devices()
     workers = {n: ShardWorker(n, store_dir, held[n],
@@ -218,7 +224,7 @@ def make_multihost_frontend(store_dir, *, hosts: int, replication: int,
         hedge_after_s=hedge_after_s, hedge_auto=hedge_auto,
         scatter_threads=scatter_threads, tracing=tracing,
         trace_slow_ms=trace_slow_ms, trace_log=trace_log,
-        pruned=pruned, prune_chunk=prune_chunk,
+        trace_ring=trace_ring, pruned=pruned, prune_chunk=prune_chunk,
         adaptive_buckets=adaptive_buckets),
         latency_models=latency_models)
     for n in fail_hosts:

@@ -39,6 +39,7 @@ from ..index.hedge import (AllReplicasFailed, AttemptFailed, HedgedExecutor,
                            ShardSim)
 from ..index.placement import ShardPlacement
 from ..obs import EventLog, KernelProfiler, Tracer
+from ..obs.trace import BatchRecorder
 from .base import ServingBackend
 from .batcher import MicroBatch, MicroBatcher
 from .metrics import ServingMetrics
@@ -242,6 +243,22 @@ class Frontend(ServingBackend):
             hit = cache[key] = worker.stage_batch(buf, n_valid)
         return hit
 
+    def _score_on(self, w: ShardWorker, g: int, terms_dev, nvalid_dev,
+                  cutoffs, topks, Q: int):
+        """One shard dispatch on worker ``w``: (candidates, method, spans).
+        Traced on the wall clock, the spans are the dispatch's own
+        ``shard_dispatch`` and the worker's stages under it, on the
+        frontend's clock, timed in whichever thread runs the dispatch;
+        otherwise None."""
+        if self._simulated or not self.tracer.enabled:
+            return (*w.score_candidates(g, terms_dev, nvalid_dev, cutoffs,
+                                        topks, Q), None)
+        rec = BatchRecorder(0, self.clock)
+        with rec.span("shard_dispatch"):
+            cands, method = w.score_candidates(g, terms_dev, nvalid_dev,
+                                               cutoffs, topks, Q, rec=rec)
+        return cands, method, rec.spans
+
     def _scatter_sequential(self, staged, buf, n_valid, cutoffs, topks,
                             Q: int):
         """Shard-by-shard hedged dispatch on one (possibly simulated)
@@ -267,8 +284,8 @@ class Frontend(ServingBackend):
                 w = self.workers[node]
                 terms_dev, nvalid_dev = self._staged(staged, w, buf,
                                                      n_valid)
-                return w.score_candidates(g, terms_dev, nvalid_dev,
-                                          cutoffs, topks, Q)
+                return self._score_on(w, g, terms_dev, nvalid_dev, cutoffs,
+                                      topks, Q)
 
             self._dispatch_seq += 1
             # rewind the event clock to the batch start per shard, track
@@ -319,8 +336,8 @@ class Frontend(ServingBackend):
                 terms_dev, nvalid_dev = staged[w.device]
                 t0 = time.perf_counter()
                 try:
-                    res = w.score_candidates(g, terms_dev, nvalid_dev,
-                                             cutoffs, topks, Q)
+                    res = self._score_on(w, g, terms_dev, nvalid_dev,
+                                         cutoffs, topks, Q)
                 except AttemptFailed:
                     continue
                 return node, time.perf_counter() - t0, res, rank
@@ -344,9 +361,10 @@ class Frontend(ServingBackend):
 
     def _scatter(self, staged, buf, n_valid, cutoffs, topks, Q: int):
         """Dispatch hook: scatter one staged batch across every shard and
-        return ([(node, latency, (cands, method))] in shard order,
-        max completion latency). Subclasses with a different transport
-        (repro.serve.rpc.RpcFrontend) override just this seam."""
+        return ([(node, latency, (cands, method[, spans]))] in shard
+        order, max completion latency); spans as ``_score_on`` gives them.
+        Subclasses with a different transport (repro.serve.rpc.RpcFrontend)
+        override just this seam."""
         if self._pool is not None and self.placement.n_shards > 1:
             results = self._scatter_concurrent(staged, buf, n_valid,
                                                cutoffs, topks, Q)
@@ -388,6 +406,7 @@ class Frontend(ServingBackend):
         try:
             results, max_done = self._scatter(staged, buf, n_valid,
                                               cutoffs, topks, Q)
+            t_sc1 = self.clock()
         except AllReplicasFailed:
             # a shard lost every replica mid-flight: the batch is already
             # out of the batcher, so answer every request FAILED instead of
@@ -409,7 +428,7 @@ class Frontend(ServingBackend):
                     r.trace, resp)
             return
         # gather in shard order — deterministic however dispatch ran
-        for node, lat, (cands, method) in results:
+        for node, lat, (cands, method, *_) in results:
             self.metrics.record_worker(node, lat)
             for i in range(Q):
                 gathered[i].append(cands[i])
@@ -439,23 +458,44 @@ class Frontend(ServingBackend):
                 bytes_saved=max(0, (pr[4] - prune0[4])
                                 - (pr[3] - prune0[3])))
 
-        # Batch-level shard_dispatch spans, copied into every member
-        # request's trace: one span per shard naming the serving node and
-        # its role — "primary" (the placement's preferred replica),
-        # "backup" (a hedged backup request won the race), or "failover"
-        # (the primary was found dead at dispatch time). The executor
-        # appends exactly one completion per dispatch in shard order, so
-        # the tail of ex.completions lines up with ``results``.
+        # Batch-level spans, copied into every member request's trace:
+        # ``scatter``, and under it one ``shard_dispatch`` per shard naming
+        # the serving node and its role — "primary" (the placement's
+        # preferred replica), "backup" (a hedged backup request won the
+        # race), or "failover" (the primary was found dead at dispatch
+        # time) — with the worker's stages under it, every one tagged with
+        # the shard, the node and the device. A dispatch that timed no
+        # spans (simulated, or remote) spans from the scatter's start for
+        # its latency. The executor appends exactly one completion per
+        # dispatch in shard order, so the tail of ex.completions lines up
+        # with ``results``. ``scatter``'s ``copies`` tag counts the
+        # device-to-host copies the batch waited on, where the workers
+        # timed their stages.
         spans = ()
         if rec is not None:
             comps = list(ex.completions)[-len(results):]
-            for g, (node, lat, _res) in enumerate(results):
+            copies = None
+            for g, (node, lat, res) in enumerate(results):
                 hedged = bool(comps[g][3]) if g < len(comps) else False
                 replicas = self.placement.replicas(g)
                 role = ("primary" if replicas and node == replicas[0]
                         else ("backup" if hedged else "failover"))
-                rec.add("shard_dispatch", t_sc0, t_sc0 + lat, shard=g,
-                        node=node, role=role, hedged=int(hedged))
+                dispatch = {"role": role, "hedged": int(hedged),
+                            "parent": "scatter"}
+                timed = res[2] if len(res) > 2 else None
+                if timed is None:
+                    rec.add("shard_dispatch", t_sc0, t_sc0 + lat, shard=g,
+                            node=node, **dispatch)
+                    continue
+                where = {"shard": g, "node": node,
+                         "device": self.workers[node].device_id}
+                for s in timed:
+                    copies = (copies or 0) + s.tags.get("copies", 0)
+                    extra = dispatch if s.name == "shard_dispatch" else {}
+                    rec.add(s.name, s.start_s, s.end_s,
+                            **{**s.tags, **where, **extra})
+            tags = {} if copies is None else {"copies": copies}
+            rec.add("scatter", t_sc0, t_sc1, **tags)
             spans = rec.finished()
 
         for i, r in enumerate(batch.requests):

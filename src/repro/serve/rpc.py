@@ -345,11 +345,13 @@ class WorkerChannel:
             self._connected_once = True
             if reconnect:
                 self.reconnects += 1
-        self._reader = threading.Thread(target=self._read_loop,
-                                        args=(sock,),
-                                        name=f"chan-read-{self.node}",
-                                        daemon=True)
-        self._reader.start()
+        # started before it is published: close() joins whatever reader
+        # it sees, and joining a thread not yet started raises
+        reader = threading.Thread(target=self._read_loop, args=(sock,),
+                                  name=f"chan-read-{self.node}",
+                                  daemon=True)
+        reader.start()
+        self._reader = reader
         if self.metrics is not None:
             self.metrics.record_channel(self.node, up=True,
                                         reconnect=reconnect)

@@ -19,10 +19,20 @@ them and runs the final selection exactly like ``index/distributed.py``'s
 score-combine, so the gathered result is bit-identical to the single-host
 QueryEngine (property-tested in tests/test_multihost.py).
 
-Tiles page through a per-worker ``DeviceTileCache`` (HBM budget per host)
-padded to the PARENT store's tallest shard, so every worker shares one
-compiled kernel per (bucket, method); ``prefetch_shard`` lets the frontend
-double-buffer the next planned shard while another worker scores.
+A worker with no HBM budget holds its shards resident as ONE packed arena
+(``PackedTileCache``): their rows concatenated, each shard addressed from
+its base row, so a host's HBM holds its own rows and nothing more, and
+the host compiles one tile shape. A worker with a budget pages per-shard
+tiles through a ``DeviceTileCache`` padded to the PARENT store's tallest
+shard, so every such worker shares one compiled kernel per (bucket,
+method); ``prefetch_shard`` lets the frontend double-buffer the next
+planned shard while another worker scores.
+
+Given a tracing recorder (``obs.trace.BatchRecorder``), a dispatch times
+its stages into it: ``shard_kernel`` (the jitted call, on the host),
+``shard_readback`` (the scores to the host: the wait for the device and
+the copy, tagged with its ``copies``) and ``shard_select`` (candidate
+selection). The frontend copies them into the batch's trace.
 
 ``fail()``/``recover()`` flip a liveness flag: a dead worker raises
 ``AttemptFailed`` on dispatch, which the frontend's HedgedExecutor turns
@@ -43,15 +53,17 @@ from ..core.query import (PruneStats, ShardPlan, make_batch_score_fn,
                           make_comp_batch_score_fn, plan_shards_subset,
                           run_paged_pruned)
 from ..core.store import open_substore
-from ..core.arena import DeviceTileCache
+from ..core.arena import DeviceTileCache, PackedTileCache
 from ..index.hedge import AttemptFailed
+from ..obs.trace import span
 from .planner import (DEFAULT_PRUNE_MIN_RATE, SHORT_QUERY_TERMS,
                       choose_method, predict_prune_rate)
 
-# One compiled scorer per (n_hashes, method, word_block), shared by EVERY
-# worker in the process: fake hosts pad tiles to the parent store's tallest
-# shard, so their dispatch shapes coincide and recompiling per worker would
-# only burn startup time (noticeable across the elasticity property sweeps).
+# One jitted scorer per (n_hashes, method, word_block), shared by EVERY
+# worker in the process: paged workers pad tiles to the parent store's
+# tallest shard, so their dispatch shapes coincide and recompiling per
+# worker would only burn startup time (noticeable across the elasticity
+# property sweeps); packed workers compile their own tile shape under it.
 _SCORE_FNS: dict[tuple[int, str, Optional[int]], object] = {}
 # ... and the fused-decode twins for workers serving compressed shards.
 _SCORE_FNS_C: dict[tuple[int, str, Optional[int]], object] = {}
@@ -115,15 +127,17 @@ class ShardWorker:
         self._local = {g: i for i, g in enumerate(self.shard_ids)}
         self.plans: list[ShardPlan] = plan_shards_subset(
             sub.layout, sub.global_row_starts, sub.shard_ids)
-        # pad tiles to the PARENT store's tallest shard: one kernel shape
-        # across every worker, not one per host's local maximum.
+        # a paged worker (an HBM budget) pads tiles to the PARENT store's
+        # tallest shard: one kernel shape across every worker, not one per
+        # host's local maximum; a resident one packs its shards unpadded.
         # ``local_pad`` instead pads to THIS host's tallest shard — smaller
         # tiles and per-worker dispatch shapes, so a per-worker tuner (the
         # ``tuner`` argument, keyed on the local geometry) can measure each
         # shard height separately instead of one tall-parent tune key
         # covering every worker.
         self.local_pad = bool(local_pad)
-        if sub.n_shards_total <= 1:
+        packed = tile_cache_bytes is None and not self.compressed
+        if packed or sub.n_shards_total <= 1:
             pad_rows = None
         elif self.local_pad:
             starts = np.asarray(sub.global_row_starts, dtype=np.int64)
@@ -158,17 +172,24 @@ class ShardWorker:
             self.density = float(mean_fn()) / float(32 * w)
         else:
             self.density = float(self.params.fpr)
-        self.tiles = DeviceTileCache(self.storage,
-                                     capacity_bytes=tile_cache_bytes,
-                                     pad_rows_to=pad_rows, device=device)
+        self.tiles = (PackedTileCache(self.storage, device=device)
+                      if packed else
+                      DeviceTileCache(self.storage,
+                                      capacity_bytes=tile_cache_bytes,
+                                      pad_rows_to=pad_rows, device=device))
+        self.device_id = int((device or jax.devices()[0]).id)
         # global slot -> original doc id (-1 for padding slots); workers
         # translate their slot planes to doc candidates host-side
         n_slots = self.layout.n_blocks * self.layout.block_docs
         self._slot_doc = np.full(n_slots, -1, dtype=np.int64)
         self._slot_doc[self.layout.doc_slot] = np.arange(self.layout.n_docs)
-        # per-local-shard device-staged addressing
-        self._args = [(p.shard, self._dev(p.row_offset),
-                       self._dev(p.block_width)) for p in self.plans]
+        # per-local-shard device-staged addressing, rebased to the shard's
+        # first row in the tile that holds it
+        self._args = [
+            (p.shard,
+             self._dev((p.row_offset.astype(np.int64)
+                        + self.tiles.row_base(p.shard)).astype(np.int32)),
+             self._dev(p.block_width)) for p in self.plans]
         self.failed = False
         self.dispatches = 0
         # dispatches abandoned mid-tile because their cancellation flag
@@ -249,7 +270,7 @@ class ShardWorker:
         return (self.compressed and
                 self.storage.shard_codec(local) in _codec.DICT_CODECS)
 
-    def score_shard(self, gshard: int, terms_dev, n_valid_dev
+    def score_shard(self, gshard: int, terms_dev, n_valid_dev, rec=None
                     ) -> tuple[np.ndarray, ShardPlan, str]:
         """Score one held shard against a staged micro-batch. Returns
         (slot scores int32 [Q, shard_slots], the shard's plan, method)."""
@@ -281,17 +302,19 @@ class ShardWorker:
             method = choose_method(self.params.n_hashes, bucket, q,
                                    self.short_query_terms)
         t0 = time.perf_counter()
-        if self._comp_shard(local):
-            self.compressed_dispatches += 1
-            dict_rows, refs = self.tiles.get_compressed(local)
-            fn = self._comp_score_fn(method, wb)
-            slots = fn(dict_rows, refs, offs, widths, terms_dev,
-                       n_valid_dev)
-        else:
-            slots = self._score_fn(method, wb)(self.tiles.get(local), offs,
-                                               widths, terms_dev,
-                                               n_valid_dev)
-        slots = np.asarray(slots)
+        with span(rec, "shard_kernel"):
+            if self._comp_shard(local):
+                self.compressed_dispatches += 1
+                dict_rows, refs = self.tiles.get_compressed(local)
+                fn = self._comp_score_fn(method, wb)
+                slots = fn(dict_rows, refs, offs, widths, terms_dev,
+                           n_valid_dev)
+            else:
+                slots = self._score_fn(method, wb)(
+                    self.tiles.get(local), offs, widths, terms_dev,
+                    n_valid_dev)
+        with span(rec, "shard_readback", {"copies": 1}):
+            slots = np.asarray(slots)
         if self.profiler is not None:
             self.profiler.record(
                 method=method, bucket=bucket, batch=q,
@@ -307,7 +330,7 @@ class ShardWorker:
 
     def score_candidates(self, gshard: int, terms_dev, n_valid_dev,
                          cutoffs: np.ndarray, topks: np.ndarray,
-                         n_live: int, *, cancelled=None
+                         n_live: int, *, cancelled=None, rec=None
                          ) -> tuple[list[tuple[np.ndarray, np.ndarray]], str]:
         """Score + select: per live query, the (doc_ids, scores) candidate
         arrays of this shard's documents — hits >= cutoffs[i] when
@@ -325,38 +348,43 @@ class ShardWorker:
         # cancellation flag: checked before the tile is scored and again
         # before candidate extraction, so a dispatch whose hedged
         # duplicate already won abandons the remaining work and raises
-        # DispatchCancelled instead of staging/scanning further.
+        # DispatchCancelled instead of staging/scanning further. ``rec``
+        # (a tracing BatchRecorder, or None) times the stages (module
+        # docstring).
         self._check_cancel(cancelled)
         with self._lock:
             pr = (self._score_pruned(gshard, terms_dev, n_valid_dev,
-                                     cutoffs, topks, n_live)
+                                     cutoffs, topks, n_live, rec)
                   if self.pruned else None)
             if pr is not None:
                 slots, plan, method = pr
             else:
-                slots, plan, method = self.score_shard(gshard, terms_dev,
-                                                       n_valid_dev)
+                slots, plan, method = self.score_shard(
+                    gshard, terms_dev, n_valid_dev, rec)
         self._check_cancel(cancelled)
-        slot0 = plan.block_start * self.layout.block_docs
-        docs = self._slot_doc[slot0: slot0 + slots.shape[1]]
-        real = docs >= 0
-        docs = docs[real]
-        out = []
-        for i in range(n_live):
-            sc = slots[i][real]
-            if topks[i] > 0:
-                order = np.lexsort((docs, -sc))[: int(topks[i])]
-                out.append((docs[order], sc[order].astype(np.int32)))
-            else:
-                m = sc >= cutoffs[i]
-                out.append((docs[m], sc[m].astype(np.int32)))
+        with span(rec, "shard_select"):
+            slot0 = plan.block_start * self.layout.block_docs
+            docs = self._slot_doc[slot0: slot0 + slots.shape[1]]
+            real = docs >= 0
+            docs = docs[real]
+            out = []
+            for i in range(n_live):
+                sc = slots[i][real]
+                if topks[i] > 0:
+                    order = np.lexsort((docs, -sc))[: int(topks[i])]
+                    out.append((docs[order], sc[order].astype(np.int32)))
+                else:
+                    m = sc >= cutoffs[i]
+                    out.append((docs[m], sc[m].astype(np.int32)))
         return out, method
 
     def _score_pruned(self, gshard: int, terms_dev, n_valid_dev,
-                      cutoffs: np.ndarray, topks: np.ndarray, n_live: int
+                      cutoffs: np.ndarray, topks: np.ndarray, n_live: int,
+                      rec=None
                       ) -> Optional[tuple[np.ndarray, ShardPlan, str]]:
         """Chunked early-exit dispatch of one held shard, or None when the
         cost model predicts no win (caller falls back to ``score_shard``).
+        Traced as one ``shard_kernel``, its read-backs counted in it.
 
         Shard-LOCAL top-k pruning is sound here: this worker only reports
         its own shard's top-k candidates, so the dynamic bound needs only
@@ -393,11 +421,15 @@ class ShardWorker:
         for i in range(n_live):
             required[i] = 0 if topks[i] else int(cutoffs[i])
         t0 = time.perf_counter()
-        slots = run_paged_pruned(
-            self.tiles, [plan], np.asarray(terms_dev), n_valid, required,
-            np.asarray(topks, dtype=np.int32),
-            n_hashes=self.params.n_hashes, chunk_terms=chunk,
-            word_block=self.word_block, stats=self.prune_stats)
+        syncs0 = self.prune_stats.syncs
+        with span(rec, "shard_kernel") as tags:
+            slots = run_paged_pruned(
+                self.tiles, [plan], np.asarray(terms_dev), n_valid, required,
+                np.asarray(topks, dtype=np.int32),
+                n_hashes=self.params.n_hashes, chunk_terms=chunk,
+                word_block=self.word_block, stats=self.prune_stats, rec=rec)
+            if tags is not None:
+                tags["copies"] = self.prune_stats.syncs - syncs0
         if self.profiler is not None:
             self.profiler.record(
                 method="lookup_p", bucket=bucket, batch=Q,

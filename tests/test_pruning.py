@@ -343,7 +343,9 @@ def test_worker_pruned_candidates_identical_zero_faults(stores):
     td_r, nd_r = w_ref.stage_batch(buf, ells)
     td_p, nd_p = w_p.stage_batch(buf, ells)
     for g in ids:
-        assert w_ref.prefetch_shard(g)
+        # an HBM-resident worker packs its shards into one arena, staged
+        # by the first prefetch
+        assert w_ref.prefetch_shard(g) == (g == ids[0])
         cand_r, m_r = w_ref.score_candidates(g, td_r, nd_r, cuts, topks,
                                              len(ells))
         cand_p, m_p = w_p.score_candidates(g, td_p, nd_p, cuts, topks,
@@ -404,8 +406,13 @@ def test_worker_local_pad_dispatch_shapes(stores):
     heights = np.diff(starts)
     short = int(np.argmin(heights))
     assert heights[short] < heights.max()     # last block group is short
-    w_local = ShardWorker("w-l", root / "raw", [short], local_pad=True)
-    w_glob = ShardWorker("w-g", root / "raw", [short])
+    # padding applies to paged workers (an HBM budget); resident ones
+    # pack their shards unpadded
+    budget = int(idx_raw.storage.nbytes())
+    w_local = ShardWorker("w-l", root / "raw", [short], local_pad=True,
+                          tile_cache_bytes=budget)
+    w_glob = ShardWorker("w-g", root / "raw", [short],
+                         tile_cache_bytes=budget)
     # local padding sizes tiles to THIS worker's tallest shard only
     assert w_local.tiles.pad_rows_to == int(heights[short])
     assert w_glob.tiles.pad_rows_to == int(heights.max())
